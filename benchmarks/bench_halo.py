@@ -1,6 +1,6 @@
 """Beyond-paper: halo materialization — replication vs collective-permute.
 
-Runs in a subprocess with 8 host devices and parses the optimized HLO for
+Runs in a subprocess with 8 virtual CPU devices and parses the optimized HLO for
 collective bytes: the paper's pre-replication pays (P−1)·H·d extra storage
 and ZERO wire bytes per sweep; exchange mode pays ~2·H·d wire bytes per
 sweep and zero storage.  (The crossover rule-of-thumb lands in
@@ -37,6 +37,9 @@ for mode in ("replicate", "exchange"):
 
 def run():
     env = dict(os.environ)
+    # 8 virtual CPU devices; the child stays off any accelerator, which the
+    # parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run(
@@ -44,8 +47,9 @@ def run():
         capture_output=True, text=True, timeout=600, env=env,
     )
     if r.returncode != 0:
-        row("halo_modes", 0.0, f"ERROR:{r.stderr[-200:]}")
-        return
+        raise RuntimeError(
+            f"halo-mode child failed (exit {r.returncode}):\n{r.stderr[-2000:]}"
+        )
     for line in r.stdout.splitlines():
         if line.startswith("RESULT"):
             _, mode, wire, counts, extra = line.split()

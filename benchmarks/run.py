@@ -58,6 +58,9 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for mod in MODULES:

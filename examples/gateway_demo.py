@@ -28,6 +28,8 @@ runs three acts:
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
+
 import asyncio
 import tempfile
 
@@ -123,4 +125,5 @@ async def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     asyncio.run(main())

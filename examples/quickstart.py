@@ -26,6 +26,8 @@ statistics from each, identifies and fits the model, and forecasts.
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
+
 import jax
 import jax.numpy as jnp
 
@@ -95,14 +97,12 @@ def main():
     print("5-step forecast (first dim):", [f"{float(v):.3f}" for v in preds[:, 0]])
 
     # 8. Where the math ran: the default "auto" backend dispatches each of
-    #    the eight primitives through MEASURED per-primitive crossovers
-    #    (repro.core.calibrate), not a hard-coded size constant.  On TPU the
-    #    first dispatch microbenchmarks and caches the thresholds; anywhere
-    #    you can also calibrate explicitly — one pass, persisted, picked up
-    #    by every later process on this machine:
+    #    the eight primitives through per-primitive crossovers
+    #    (repro.core.calibrate), not a hard-coded size constant.  They come
+    #    from the built-in table until you measure explicitly — one pass,
+    #    persisted, picked up by every later process on this machine:
     #
-    #        from repro.core.calibrate import calibrate
-    #        get_backend("auto").set_table(calibrate())   # measures + caches
+    #        python -m repro.core.calibrate --tune
     #
     from repro.core.backend import get_backend
     from repro.core.calibrate import cache_path
@@ -270,4 +270,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,8 @@ finalize — the weak-memory monoid doing LM serving observability.
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
+
 import time
 
 import jax
@@ -74,4 +76,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,8 @@ the one-step predictor via the Pallas banded_matvec kernel.
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
+
 import time
 
 import jax
@@ -66,4 +68,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -10,6 +10,8 @@ neighbours (paper Fig. 5-8).
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
+
 import jax
 import jax.numpy as jnp
 
@@ -61,4 +63,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

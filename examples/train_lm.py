@@ -12,6 +12,8 @@ unigram entropy); pass --full for the real 125M config (TPU-scale).
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
+
 import argparse
 
 from repro.launch.train import main as train_main
@@ -42,4 +44,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -53,9 +53,11 @@ Backends in the registry:
   ``"pallas"``  explicit VMEM tile kernels (`repro.kernels.window_stats`,
                 `repro.kernels.banded_matvec`,
                 `repro.kernels.segment_dft`) — the TPU re-instantiation of
-                the paper's §12 GPU shared-memory scheme.  Runs in interpret
-                mode off-TPU so CPU tests exercise the identical tiling.
-                Every primitive has a real kernel: the spectral ones
+                the paper's §12 GPU shared-memory scheme.  Compiled for the
+                TPU; off the TPU it exists only when a caller registers an
+                interpret-mode instance (``PallasBackend(interpret=True)``,
+                as the test suite does), so no run lands in interpret mode
+                unasked.  Every primitive has a real kernel: the spectral ones
                 evaluate the fixed-L real DFT as tiled matmuls against
                 precomputed twiddle/window matrices, and
                 ``fused_plan_update`` is a persistent MEGAKERNEL serving a
@@ -63,15 +65,13 @@ Backends in the registry:
                 through the calibrated block table
                 (``calibrate(tune_blocks=True)``) unless pinned explicitly.
   ``"auto"``    per-call policy (the default): each primitive routes to
-                Pallas once its problem size crosses a **measured**,
-                per-primitive threshold (`repro.core.calibrate`).  The
-                thresholds resolve lazily at first dispatch — a cached
-                calibration if one exists, a fresh microbenchmark pass on
-                TPU (persisted for next time), the built-in default table
-                otherwise (off-accelerator that table says "always jnp":
-                interpret mode is a testing vehicle, not a serving path).
-                There is no hard-coded row constant left in the policy;
-                re-measure with ``repro.core.calibrate.calibrate()``.
+                Pallas once its problem size crosses a per-primitive
+                threshold (`repro.core.calibrate`).  The thresholds resolve
+                lazily at first dispatch, without measuring anything: a
+                table the user installed (``python -m repro.core.calibrate
+                --tune`` / ``--bless``), else the built-in default table
+                (off-accelerator that table says "always jnp": interpret
+                mode is a testing vehicle, not a serving path).
 
 Registering a new backend (a GPU Triton port, a CPU-vectorized build, …):
 
@@ -214,6 +214,11 @@ class Backend(Protocol):
         ...
 
 
+# f32 contractions: TPU's default matmul precision rounds f32 operands to
+# bf16, which the statistics cannot afford.
+_F32 = jax.lax.Precision.HIGHEST
+
+
 def _as_2d(x: jax.Array) -> jax.Array:
     return x[:, None] if x.ndim == 1 else x
 
@@ -223,7 +228,8 @@ class JnpBackend:
 
     All accumulation happens in float32 whatever the input dtype, matching
     the Pallas kernels' ``preferred_element_type`` so cross-backend parity
-    holds for bf16 inputs too.
+    holds for bf16 inputs too.  Contractions ask for f32 precision: on TPU
+    the default would round f32 operands to bf16.
     """
 
     name = "jnp"
@@ -239,7 +245,7 @@ class JnpBackend:
                 valid = (idx + h) <= (n - 1)
                 shifted = x[jnp.clip(idx + h, 0, n - 1)]
                 shifted = jnp.where(valid[:, None], shifted, 0.0)
-                return jnp.einsum("ti,tj->ij", x, shifted)
+                return jnp.einsum("ti,tj->ij", x, shifted, precision=_F32)
 
             return jax.vmap(one_ragged)(jnp.arange(max_lag + 1))
 
@@ -248,7 +254,7 @@ class JnpBackend:
             shifted = jax.lax.dynamic_slice_in_dim(x, h, n - max_lag, axis=0)
             # Only the common full-length prefix enters this vectorized form;
             # the ragged tail (k in [n-max_lag, n-h)) is added below.
-            return jnp.einsum("ti,tj->ij", head, shifted)
+            return jnp.einsum("ti,tj->ij", head, shifted, precision=_F32)
 
         full = jax.vmap(one)(jnp.arange(max_lag + 1))
 
@@ -259,7 +265,7 @@ class JnpBackend:
             valid = (k + h) <= (n - 1)
             xk = x[jnp.clip(k, 0, n - 1)]
             xkh = x[jnp.clip(k + h, 0, n - 1)]
-            contrib = jnp.einsum("ti,tj->tij", xk, xkh)
+            contrib = jnp.einsum("ti,tj->tij", xk, xkh, precision=_F32)
             return jnp.sum(jnp.where(valid[:, None, None], contrib, 0.0), axis=0)
 
         if max_lag > 0:
@@ -278,7 +284,7 @@ class JnpBackend:
 
         def one(h):
             shifted = jax.lax.dynamic_slice_in_dim(y_padded, h, L, axis=0)
-            return jnp.einsum("ti,tj->ij", head, shifted)
+            return jnp.einsum("ti,tj->ij", head, shifted, precision=_F32)
 
         return jax.vmap(one)(jnp.arange(max_lag + 1))
 
@@ -315,7 +321,9 @@ class JnpBackend:
         valid = (cols >= 0) & (cols < d)
         xn = jnp.take(x.astype(jnp.float32), jnp.clip(cols, 0, d - 1), axis=-1)
         xn = jnp.where(valid, xn, 0.0)
-        return jnp.einsum("...dw,dw->...d", xn, diags.astype(jnp.float32))
+        return jnp.einsum(
+            "...dw,dw->...d", xn, diags.astype(jnp.float32), precision=_F32
+        )
 
     def fused_lagged_moments(
         self,
@@ -365,7 +373,7 @@ class JnpBackend:
             if detrend:
                 seg = seg - seg.mean(axis=0)
             f = jnp.fft.rfft(seg * taper[:, None], axis=0)  # (F, d)
-            return jnp.einsum("fi,fj->fij", f, jnp.conj(f))
+            return jnp.einsum("fi,fj->fij", f, jnp.conj(f), precision=_F32)
 
         return jax.vmap(one)(segments)
 
@@ -434,9 +442,10 @@ class PallasBackend:
         the fused-plan megakernel.
       block_rows: row tile for the banded matvec.
       block_s: segments staged per grid step in the segment-DFT kernels.
-      interpret: force Pallas interpret mode.  ``None`` (default) resolves
-        per call: compiled on TPU, interpret everywhere else — so the same
-        backend object validates on CPU and serves on TPU.
+      interpret: run the kernels in Pallas interpret mode.  ``None``
+        (default) means compiled, and raises off the TPU — interpret mode
+        is never chosen for the caller; pass ``True`` to validate the
+        kernels on CPU.
 
     Every block argument defaults to ``None`` — the ops entry points then
     resolve the tile size through the calibrated per-platform block table
@@ -454,21 +463,25 @@ class PallasBackend:
         block_s: Optional[int] = None,
         interpret: Optional[bool] = None,
     ):
+        if interpret is None:
+            platform = jax.default_backend()
+            if platform != "tpu":
+                raise RuntimeError(
+                    f"Pallas kernels compile for TPU, and JAX's default "
+                    f"backend is {platform!r}; pass interpret=True to run "
+                    f"them in interpret mode"
+                )
+            interpret = False
         self.block_t = block_t
         self.block_rows = block_rows
         self.block_s = block_s
         self.interpret = interpret
 
-    def _interp(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return jax.default_backend() != "tpu"
-
     def lagged_sums(self, x: jax.Array, max_lag: int) -> jax.Array:
         from ..kernels.window_stats import ops as ws
 
         return ws.lagged_sums(
-            x, max_lag, block_t=self.block_t, interpret=self._interp()
+            x, max_lag, block_t=self.block_t, interpret=self.interpret
         )
 
     def masked_lagged_sums(
@@ -477,14 +490,14 @@ class PallasBackend:
         from ..kernels.window_stats import ops as ws
 
         return ws.masked_lagged_sums(
-            y_padded, start_mask, max_lag, block_t=self.block_t, interpret=self._interp()
+            y_padded, start_mask, max_lag, block_t=self.block_t, interpret=self.interpret
         )
 
     def windowed_moments(self, x: jax.Array, window: int) -> jax.Array:
         from ..kernels.window_stats import ops as ws
 
         return ws.windowed_moments(
-            x, window, block_t=self.block_t, interpret=self._interp()
+            x, window, block_t=self.block_t, interpret=self.interpret
         )
 
     def segment_fft_power(
@@ -497,7 +510,7 @@ class PallasBackend:
             taper,
             detrend,
             block_s=self.block_s,
-            interpret=self._interp(),
+            interpret=self.interpret,
         )
 
     def banded_matvec(self, diags: jax.Array, x: jax.Array) -> jax.Array:
@@ -508,7 +521,7 @@ class PallasBackend:
         # kernel contract is (d, nrhs): fold any leading batch axes into nrhs.
         xr = x.reshape(-1, d).T if lead else x
         y = bmv.banded_matvec(
-            diags, xr, block_rows=self.block_rows, interpret=self._interp()
+            diags, xr, block_rows=self.block_rows, interpret=self.interpret
         )
         return y.T.reshape(*lead, d) if lead else y
 
@@ -527,7 +540,7 @@ class PallasBackend:
             max_lag,
             window,
             block_t=self.block_t,
-            interpret=self._interp(),
+            interpret=self.interpret,
         )
 
     def segment_csd(
@@ -540,7 +553,7 @@ class PallasBackend:
             taper,
             detrend,
             block_s=self.block_s,
-            interpret=self._interp(),
+            interpret=self.interpret,
         )
 
     def fused_plan_update(
@@ -570,22 +583,23 @@ class PallasBackend:
             detrend,
             stage_dtype=stage_dtype,
             block_t=self.block_t,
-            interpret=self._interp(),
+            interpret=self.interpret,
         )
 
 
 class AutoBackend:
-    """Per-call dispatch by *measured* crossover, not a hard-coded constant.
+    """Per-call dispatch by crossover table, not a hard-coded constant.
 
     Each primitive routes to the Pallas tile kernel once its problem size
     (rows for the windowed contractions, banded dimension for the matvec,
     total staged samples S·L for the segment DFT) reaches that primitive's
-    calibrated crossover threshold (`repro.core.calibrate`).  The table is
-    resolved lazily at the first dispatch: a cached measurement for this
-    platform if one exists, a fresh microbenchmark pass on TPU (persisted),
-    else the built-in default table — which off-accelerator says "always
-    jnp", since interpret-mode Pallas is a validation vehicle ~100× slower
-    than XLA.
+    crossover threshold (`repro.core.calibrate`).  The table is resolved
+    lazily at the first dispatch and never measured there: the table the
+    user installed for this platform if one exists, else the built-in
+    default table — which off-accelerator says "always jnp", since
+    interpret-mode Pallas is a validation vehicle ~100× slower than XLA.
+    The Pallas side is the registered ``"pallas"`` backend unless one is
+    passed in.
 
     Inject or refresh the policy at runtime:
 
@@ -601,13 +615,17 @@ class AutoBackend:
         table=None,
     ):
         self._jnp = jnp_backend or JnpBackend()
-        self._pallas = pallas_backend or PallasBackend()
+        self._pallas_backend = pallas_backend
         self._table = table
+
+    @property
+    def _pallas(self) -> Backend:
+        return self._pallas_backend or get_backend("pallas")
 
     @property
     def table(self):
         """The active `repro.core.calibrate.CalibrationTable` (resolving it
-        on first access — cache > TPU auto-measure > built-in default)."""
+        on first access — installed table > built-in default)."""
         if self._table is None:
             from .calibrate import resolve_table
 
@@ -714,8 +732,8 @@ class AutoBackend:
 class CircuitBreakerBackend:
     """Self-healing dispatch: quarantine a raising primitive, keep serving.
 
-    Wraps a ``primary`` backend (default: Pallas) and a ``fallback`` oracle
-    (default: jnp).  Each primitive carries its own breaker:
+    Wraps a ``primary`` backend (default: the registered ``"pallas"``) and
+    a ``fallback`` oracle (default: jnp).  Each primitive carries its own breaker:
 
       * **closed** (healthy): dispatch goes to the primary.  A primary
         raise — a kernel build failure, an injected
@@ -752,7 +770,7 @@ class CircuitBreakerBackend:
     ):
         if trip_after < 1 or cooldown_calls < 1:
             raise ValueError("trip_after and cooldown_calls must be >= 1")
-        self._primary = primary if primary is not None else PallasBackend()
+        self._primary = primary if primary is not None else get_backend("pallas")
         self._fallback = fallback if fallback is not None else JnpBackend()
         self.trip_after = trip_after
         self.cooldown_calls = cooldown_calls
@@ -841,9 +859,12 @@ class CircuitBreakerBackend:
             self._state.pop(primitive, None)
 
 
-_REGISTRY: Dict[str, Backend] = {
+# "pallas" is built at its first lookup (None until then): a compiled
+# PallasBackend exists only on the TPU, and off it a caller that wants the
+# kernels registers an interpret-mode instance first.
+_REGISTRY: Dict[str, Optional[Backend]] = {
     "jnp": JnpBackend(),
-    "pallas": PallasBackend(),
+    "pallas": None,
     "auto": AutoBackend(),
 }
 _DEFAULT = "auto"
@@ -873,10 +894,11 @@ def get_backend(spec: BackendSpec = None) -> Backend:
     if spec is None:
         spec = _DEFAULT
     if isinstance(spec, str):
-        try:
-            return _REGISTRY[spec]
-        except KeyError:
+        if spec not in _REGISTRY:
             raise KeyError(
                 f"unknown backend {spec!r}; registered: {list_backends()}"
-            ) from None
+            )
+        if _REGISTRY[spec] is None:
+            _REGISTRY[spec] = PallasBackend()
+        return _REGISTRY[spec]
     return spec

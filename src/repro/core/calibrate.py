@@ -18,11 +18,13 @@ it with measurement:
     ``REPRO_CALIB_CACHE``), so one calibration pass serves every later
     process on the same machine;
   * `repro.core.backend.AutoBackend` resolves its thresholds lazily at the
-    first dispatch through :func:`resolve_table`: a cached measured table
-    if one exists, else — on TPU, or when ``REPRO_AUTO_CALIBRATE=1`` — a
-    fresh :func:`calibrate` run persisted for next time, else the built-in
-    :func:`default_table` (off-accelerator the Pallas path is interpret
-    mode, never profitable, so the default is "always jnp").
+    first dispatch through :func:`resolve_table`, which never measures: the
+    table the user installed for this platform (``--tune`` / ``--bless``
+    below) if one exists, else the built-in :func:`default_table`
+    (off-accelerator the Pallas path is interpret mode, never profitable,
+    so the default is "always jnp").  Measuring is always an explicit step:
+    a first dispatch happens while a served program is being traced, where
+    a timing would time the tracer.
 
 The built-in defaults are a *fallback*, not policy: any measured table,
 cached or injected (``AutoBackend(table=...)``), overrides them.
@@ -258,31 +260,12 @@ def save_table(table: CalibrationTable, path: Optional[str] = None) -> str:
     return path
 
 
-def _autocalibrate_default(platform: str) -> bool:
-    env = os.environ.get("REPRO_AUTO_CALIBRATE")
-    if env is not None:
-        return env not in ("", "0", "false", "False")
-    # First use on a TPU pays one measurement pass and caches it; elsewhere
-    # the interpret-mode "measurement" would only confirm the default inf.
-    return platform == "tpu"
-
-
-def resolve_table(
-    platform: Optional[str] = None, autocalibrate: Optional[bool] = None
-) -> CalibrationTable:
+def resolve_table(platform: Optional[str] = None) -> CalibrationTable:
     """The table the ``"auto"`` backend should dispatch with, resolved at
-    first use: cached measurement > fresh measurement (TPU or
-    ``REPRO_AUTO_CALIBRATE=1``) > built-in default."""
+    first use without measuring: the installed (cached) table for this
+    platform > the built-in default."""
     platform = platform or jax.default_backend()
-    cached = load_table(platform)
-    if cached is not None:
-        set_active_table(cached)
-        return cached
-    if autocalibrate is None:
-        autocalibrate = _autocalibrate_default(platform)
-    if autocalibrate:
-        return calibrate(save=True)
-    table = default_table(platform)
+    table = load_table(platform) or default_table(platform)
     set_active_table(table)
     return table
 
@@ -490,10 +473,8 @@ def calibrate(
         )
     set_active_table(table)
     if save:
-        # The measured table is the product; the cache is an optimization.
-        # ``calibrate`` can run implicitly at the auto backend's first
-        # dispatch (resolve_table), so an unwritable cache location must
-        # not crash the user's first estimator call.
+        # The measured table is the product; the cache is an optimization,
+        # so an unwritable cache location only warns.
         try:
             save_table(table, path)
         except OSError as e:
@@ -522,11 +503,14 @@ def _tune_blocks_into(
     backend and record each winner in ``table.blocks`` (in place).
 
     The search times the SAME workload closures the crossover pass uses, one
-    fresh ``PallasBackend`` per candidate so the tile size under test is the
+    fresh ``PallasBackend`` per candidate (interpreted exactly when the
+    registered ``"pallas"`` is) so the tile size under test is the
     explicit override — the resolution chain (override > table > default)
     guarantees the measurement cannot read the very table it is writing.
     """
-    from .backend import PallasBackend
+    from .backend import PallasBackend, get_backend
+
+    interpret = get_backend("pallas").interpret
 
     loads = _workloads(n, d, max_lag, window, nperseg, bandwidth)
     for prim, params in TUNABLE_BLOCKS.items():
@@ -534,7 +518,7 @@ def _tune_blocks_into(
         for param in params:
             best_c, best_t = None, math.inf
             for cand in BLOCK_CANDIDATES[param]:
-                be = PallasBackend(**{param: cand})
+                be = PallasBackend(interpret=interpret, **{param: cand})
                 t = _time(loads[prim](be), iters, warmup)
                 if verbose:
                     print(

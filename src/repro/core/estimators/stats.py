@@ -175,13 +175,15 @@ def autocovariance_sharded(
     backend-agnostic psum.
     """
 
-    from ...parallel.sharding import psum_tree, shard_map_compat
+    from ...parallel.sharding import psum_tree
 
     def local(blocks_local):
         partial = block_lag_sums(blocks_local, spec, max_lag, backend=backend)
         return psum_tree(jnp.sum(partial, axis=0), axis)
 
-    s = shard_map_compat(local, mesh=mesh, in_specs=P(axis), out_specs=P())(blocks)
+    s = jax.shard_map(
+        local, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False
+    )(blocks)
     norm = gamma_normalizer(spec.n, max_lag, normalization)
     return s * norm[:, None, None]
 

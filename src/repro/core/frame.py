@@ -603,7 +603,7 @@ class SeriesFrame(_DeferredRequests):
             stat_sum = jax.tree.map(lambda l: jnp.sum(l, axis=0), stats)
             sample_sum = jnp.sum(ssums, axis=0)
         else:
-            from ..parallel.sharding import psum_tree, shard_map_compat
+            from ..parallel.sharding import psum_tree
 
             per_dev = spec.num_blocks // store.mesh.shape[store.axis]
 
@@ -619,8 +619,9 @@ class SeriesFrame(_DeferredRequests):
                 )
                 return psum_tree(partial, store.axis)
 
-            fn = shard_map_compat(
-                local, mesh=store.mesh, in_specs=P(store.axis), out_specs=P()
+            fn = jax.shard_map(
+                local, mesh=store.mesh, in_specs=P(store.axis), out_specs=P(),
+                check_vma=False,
             )
             stat_sum, sample_sum = fn(store.blocks)
 

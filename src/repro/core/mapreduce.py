@@ -245,7 +245,7 @@ def sharded_window_map_reduce(
         local_sum = jax.tree.map(lambda l: jnp.sum(l, axis=0), partials)
         return psum_tree(local_sum, axis)
 
-    from ..parallel.sharding import shard_map_compat
-
-    fn = shard_map_compat(local, mesh=mesh, in_specs=P(axis), out_specs=P())
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False
+    )
     return fn(blocks)

@@ -3,8 +3,9 @@
 Paper §6.1: with a b-banded transition A, node i computes its rows of
 x̂ = A x from x^{P_i⁺} (own rows ± b halo) — O(d·(2b+1)) total work.  The
 VMEM instantiation: each grid step stages its row tile of the diagonals plus
-THREE x tiles (previous/core/next — the spatial halo) and contracts the 2b+1
-shifted views with the diagonal columns on the VPU.
+THREE x tiles (previous/core/next — the spatial halo, copied side by side
+into one VMEM scratch) and contracts the 2b+1 shifted views with the
+diagonal columns on the VPU.
 
 Requires b ≤ block_rows (one-tile halo), the same constraint as the paper's
 b ≪ d partitioning.
@@ -16,21 +17,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(diags_ref, x_prev_ref, x_core_ref, x_next_ref, y_ref, *, bandwidth: int, block_rows: int, d: int):
+def _kernel(diags_ref, x_prev_ref, x_core_ref, x_next_ref, y_ref, xs_ref, *, bandwidth: int, block_rows: int, d: int):
     i = pl.program_id(0)
     b = bandwidth
     r = block_rows
 
     diags = diags_ref[...]  # (r, 2b+1)
-    xs = jnp.concatenate([x_prev_ref[...], x_core_ref[...], x_next_ref[...]], axis=0)
+    # previous/core/next x tiles side by side in one VMEM scratch, so each
+    # band offset is a static ref slice
+    xs_ref[:r, :] = x_prev_ref[...]
+    xs_ref[r : 2 * r, :] = x_core_ref[...]
+    xs_ref[2 * r :, :] = x_next_ref[...]
     # global row of tile start; rows are i·r + [0, r)
     row0 = i * r
     acc = jnp.zeros(y_ref.shape, jnp.float32)
     for o in range(-b, b + 1):
         # x[row + o] lives at local index (r + o) + [0, r) within xs
-        xo = jax.lax.dynamic_slice_in_dim(xs, r + o, r, axis=0)  # (r, nrhs)
+        xo = xs_ref[r + o : 2 * r + o, :]  # (r, nrhs)
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
         valid = ((rows + o) >= 0) & ((rows + o) < d)
         contrib = diags[:, b + o][:, None] * xo
@@ -76,5 +82,6 @@ def banded_matvec_pallas(
         ],
         out_specs=pl.BlockSpec((block_rows, nrhs), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((d, nrhs), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((3 * block_rows, nrhs), jnp.float32)],
         interpret=interpret,
     )(diags, x, x, x)

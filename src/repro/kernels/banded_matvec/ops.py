@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..tiling import align_rows, resolve_block
 from .kernel import banded_matvec_pallas
 from .ref import banded_matvec_ref
 
@@ -41,7 +42,7 @@ def _forward(diags, x, block_rows: int, interpret: bool):
     """Padded Pallas forward for (d, 2b+1) diags and (d, nrhs) x."""
     d, w = diags.shape
     b = (w - 1) // 2
-    br = max(min(block_rows, d), b)
+    br = align_rows(max(min(block_rows, d), b))
     d_pad = -(-d // br) * br
     if d_pad != d:
         diags = jnp.pad(diags, ((0, d_pad - d), (0, 0)))
@@ -117,8 +118,6 @@ def banded_matvec(
 
     Returns y with x's trailing shape, float32.
     """
-    from ..tiling import resolve_block
-
     block_rows = resolve_block("banded_matvec", "block_rows", block_rows)
     return _banded_matvec_jit(
         diags, x, block_rows=block_rows, interpret=interpret

@@ -2,9 +2,9 @@
 
 Handles everything the device kernel must not: reach-aware zero-extension,
 tile padding with a halo tile only when some member reaches past its start
-row, per-Welch-member candidate-offset tables (the stride alignment math,
-done once in jnp so the kernel's segment loop is a static unroll), twiddle
-construction, optional bf16 staging, and the tile-size resolution through
+row, per-Welch-member segment counts (the kernel derives each tile's
+stride-aligned starts itself from ``z0``), twiddle construction, optional
+bf16 staging, and the tile-size resolution through
 the calibrated block table (`repro.kernels.tiling.resolve_block`).
 
 The block size is resolved OUTSIDE the jit boundary: the inner program is
@@ -27,35 +27,6 @@ import jax.numpy as jnp
 from ..segment_dft.ref import dft_power_matrices
 from ..tiling import clamp_block_t, pad_tiles, resolve_block
 from .kernel import fused_plan_megakernel_pallas
-
-
-def _candidate_offsets(
-    z0: jax.Array,
-    L: int,
-    num_tiles: int,
-    block_t: int,
-    step: int,
-    start_mask: jax.Array,
-) -> jax.Array:
-    """(num_tiles, n_cand) int32 local segment starts per tile, −1 invalid.
-
-    A candidate is a local row ``c`` whose global index ``z0 + c`` is a
-    multiple of ``step`` with ``c < L`` and ``start_mask[c]`` — exactly the
-    segment grid of `repro.core.estimators.spectral.welch_chunk_kernel`,
-    re-derived per tile: entry ``[i, k]`` is ``c − i·block_t`` (the start's
-    offset inside tile i's resident rows) so the kernel can slice the
-    segment straight out of VMEM.  ``n_cand = block_t // step + 1`` bounds
-    the aligned starts any single tile can contain.
-    """
-    n_cand = block_t // step + 1
-    tile0 = jnp.arange(num_tiles, dtype=jnp.int32)[:, None] * block_t
-    base = (-(z0 + tile0)) % step  # first aligned local row ≥ tile start
-    c = tile0 + base + jnp.arange(n_cand, dtype=jnp.int32)[None, :] * step
-    off = c - tile0
-    valid = (
-        (off < block_t) & (c < L) & start_mask[jnp.clip(c, 0, L - 1)]
-    )
-    return jnp.where(valid, off, -1).astype(jnp.int32)
 
 
 @functools.partial(
@@ -108,7 +79,6 @@ def _fused_plan_update_jit(
     head_p = pad_tiles(head, bt, halo=halo)
     y_p = pad_tiles(y, bt, halo=halo)
     m_p = pad_tiles(m, bt, halo=halo)
-    num_tiles = y_p.shape[0] // bt
 
     if stage_dtype is not None:
         # bf16 staging: the HBM↔VMEM stream narrows; the kernel widens back
@@ -118,10 +88,6 @@ def _fused_plan_update_jit(
         y_p = y_p.astype(dt)
 
     z0 = jnp.asarray(z0, jnp.int32)
-    offset_tables = tuple(
-        _candidate_offsets(z0, L, num_tiles, bt, step, start_mask)
-        for step in seg_steps
-    )
     twiddles = [
         dft_power_matrices(Lseg, taper)
         for Lseg, taper in zip(seg_lens, tapers)
@@ -133,18 +99,23 @@ def _fused_plan_update_jit(
         head_p,
         y_p,
         m_p,
-        offset_tables,
+        z0.reshape(1, 1),
         cos_mats,
         sin_mats,
         max_lag,
         windows,
         seg_lens,
+        seg_steps,
         detrend=detrend,
         block_t=bt,
         interpret=interpret,
     )
+    # a segment starts at every masked start whose global index is a
+    # multiple of the member's stride — the kernel's weight-1 candidates
+    rows = z0 + jnp.arange(L, dtype=jnp.int32)
     n_segs = tuple(
-        jnp.sum((offs >= 0).astype(jnp.float32)) for offs in offset_tables
+        jnp.sum((start_mask & (rows % step == 0)).astype(jnp.float32))
+        for step in seg_steps
     )
     return lag, mom, psds, n_segs
 

@@ -36,6 +36,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+def _compiler_params(vmem_limit):
+    if vmem_limit is None:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit))
+
+
+def _twiddle_dot(twiddles, y):
+    """(L, F) twiddles against an (L, d) segment, contracted over time in
+    f32 (TPU's default precision would round both operands to bf16)."""
+    return jax.lax.dot_general(
+        twiddles,
+        y,
+        (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )  # (F, d)
 
 
 def _dft_power_kernel(
@@ -49,12 +68,8 @@ def _dft_power_kernel(
             y = y - jnp.mean(y, axis=0, keepdims=True)
         # Two MXU contractions per segment: every frequency bin of every
         # channel at once, contracted over the resident time axis.
-        re = jax.lax.dot_general(
-            cosm, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (F, d)
-        im = jax.lax.dot_general(
-            sinm, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        re = _twiddle_dot(cosm, y)  # (F, d)
+        im = _twiddle_dot(sinm, y)
         out_ref[j] = re * re + im * im
 
 
@@ -67,12 +82,8 @@ def _csd_kernel(
         y = seg_ref[j].astype(jnp.float32)  # (L, d)
         if detrend:
             y = y - jnp.mean(y, axis=0, keepdims=True)
-        re = jax.lax.dot_general(
-            cosm, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (F, d)
-        im = jax.lax.dot_general(
-            sinm, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        re = _twiddle_dot(cosm, y)  # (F, d)
+        im = _twiddle_dot(sinm, y)
         # f_i conj(f_j) with f = re + i·im, emitted as two real planes
         # (Pallas has no complex dtypes); ops.py recombines re + i·im.
         re_ref[j] = re[:, :, None] * re[:, None, :] + im[:, :, None] * im[:, None, :]
@@ -86,6 +97,7 @@ def segment_csd_pallas(
     *,
     detrend: bool = True,
     block_s: int = 8,
+    vmem_limit: "int | None" = None,
     interpret: bool = False,
 ) -> tuple:
     """Per-segment cross-spectral products of a zero-padded segment stack.
@@ -123,6 +135,7 @@ def segment_csd_pallas(
             jax.ShapeDtypeStruct((s_pad, F, d, d), jnp.float32),
             jax.ShapeDtypeStruct((s_pad, F, d, d), jnp.float32),
         ],
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
     )(segments, cos_mat, sin_mat)
 
@@ -134,6 +147,7 @@ def segment_dft_power_pallas(
     *,
     detrend: bool = True,
     block_s: int = 8,
+    vmem_limit: "int | None" = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Per-segment one-sided DFT power of a zero-padded segment stack.
@@ -144,6 +158,7 @@ def segment_dft_power_pallas(
       cos_mat / sin_mat: (L, F) taper-folded twiddle matrices (see module
         docstring); F = L // 2 + 1.
       detrend: subtract each segment's per-channel mean before the taper.
+      vmem_limit: scoped VMEM bytes to compile with (None: the default).
 
     Returns (S_padded, F, d) float32: |rfft((seg − mean) · taper)|².
     """
@@ -169,5 +184,6 @@ def segment_dft_power_pallas(
         ],
         out_specs=pl.BlockSpec((block_s, F, d), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((s_pad, F, d), jnp.float32),
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
     )(segments, cos_mat, sin_mat)

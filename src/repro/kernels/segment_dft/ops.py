@@ -10,7 +10,11 @@ registry's ``segment_fft_power`` / ``segment_csd`` primitives
 ``block_s`` resolves through the calibrated block table
 (`repro.kernels.tiling.resolve_block`) OUTSIDE the jit boundary — a newly
 installed table changes the next call's geometry instead of being baked
-into a stale trace; pass ``block_s=`` explicitly to override.
+into a stale trace; pass ``block_s=`` explicitly to override.  Either way
+it is then bounded by VMEM at the call's ``(L, F, d)`` (:func:`_fit_block_s`):
+the cross-spectral output grows as ``F·d²`` per segment, so at d ≈ 100 a
+single segment's double-buffered block already fills the default scoped
+VMEM, and the kernel is given a larger limit instead.
 """
 from __future__ import annotations
 
@@ -25,14 +29,41 @@ from .kernel import segment_csd_pallas, segment_dft_power_pallas
 from .ref import dft_power_matrices, segment_csd_ref, segment_dft_power_ref
 
 
+# Scoped VMEM the blocks of one grid step may take before the kernel asks
+# the compiler for more: under the TPU's 16 MiB default scoped limit, with
+# room for the compiler's own temporaries.
+_VMEM_BUDGET = 12 << 20
+
+
+def _tile_bytes(rows: int, cols: int) -> int:
+    """Bytes of an f32 (rows, cols) VMEM tile, padded to the (8, 128)
+    layout."""
+    return -(-rows // 8) * 8 * (-(-cols // 128) * 128) * 4
+
+
+def _fit_block_s(block_s: int, s: int, L: int, d: int, csd: bool):
+    """Largest segments-per-step ≤ ``block_s`` (and ≤ the segment count)
+    whose double-buffered blocks fit :data:`_VMEM_BUDGET`, and the VMEM
+    limit to compile with (None: the default suffices)."""
+    F = L // 2 + 1
+    out = 2 * F * _tile_bytes(d, d) if csd else _tile_bytes(F, d)
+    per_seg = 2 * (_tile_bytes(L, d) + out)  # double-buffered in + out
+    fixed = 2 * 2 * _tile_bytes(L, F)  # the two resident twiddle blocks
+    bs = max(1, min(block_s, s, (_VMEM_BUDGET - fixed) // per_seg))
+    need = fixed + bs * per_seg
+    if need <= _VMEM_BUDGET:
+        return bs, None
+    # one segment's blocks alone overflow the budget: ask for them plus the
+    # kernel body's own copy of the per-segment output and some headroom
+    return bs, need + out + (4 << 20)
+
+
 def _pad_segments(segments: jax.Array, block_s: int):
     s = segments.shape[0]
-    block_s = max(1, min(block_s, max(s, 1)))
     s_pad = -(-max(s, 1) // block_s) * block_s
-    segs = jnp.pad(
+    return jnp.pad(
         segments.astype(jnp.float32), ((0, s_pad - s), (0, 0), (0, 0))
     )
-    return segs, block_s
 
 
 def _check_segments(segments: jax.Array, taper: jax.Array):
@@ -56,9 +87,15 @@ def _segment_fft_power_jit(
 ) -> jax.Array:
     s, L, d = segments.shape
     C, Sn = dft_power_matrices(L, taper)
-    segs, block_s = _pad_segments(segments, block_s)
+    block_s, vmem_limit = _fit_block_s(block_s, s, L, d, csd=False)
     out = segment_dft_power_pallas(
-        segs, C, Sn, detrend=detrend, block_s=block_s, interpret=interpret
+        _pad_segments(segments, block_s),
+        C,
+        Sn,
+        detrend=detrend,
+        block_s=block_s,
+        vmem_limit=vmem_limit,
+        interpret=interpret,
     )
     return out[:s]
 
@@ -107,9 +144,15 @@ def _segment_csd_jit(
 ) -> jax.Array:
     s, L, d = segments.shape
     C, Sn = dft_power_matrices(L, taper)
-    segs, block_s = _pad_segments(segments, block_s)
+    block_s, vmem_limit = _fit_block_s(block_s, s, L, d, csd=True)
     re, im = segment_csd_pallas(
-        segs, C, Sn, detrend=detrend, block_s=block_s, interpret=interpret
+        _pad_segments(segments, block_s),
+        C,
+        Sn,
+        detrend=detrend,
+        block_s=block_s,
+        vmem_limit=vmem_limit,
+        interpret=interpret,
     )
     return jax.lax.complex(re[:s], im[:s])
 

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 __all__ = [
     "DEFAULT_BLOCKS",
     "resolve_block",
+    "align_rows",
     "clamp_block_t",
     "pad_tiles",
     "pad_to_multiple",
@@ -96,17 +97,29 @@ def resolve_block(
     return default_block(primitive, param)
 
 
+# Row tiles are rounded up to this many rows: the TPU lays a block's
+# second-to-last axis out in tiles of 8 rows for f32 and 16 for bf16 (the
+# optional staging dtype), and refuses a block that is not a whole number
+# of them.  Zero rows past the series contribute nothing to any kernel.
+SUBLANE_ALIGN = 16
+
+
+def align_rows(rows: int) -> int:
+    """``rows`` rounded up to a multiple of :data:`SUBLANE_ALIGN`."""
+    return -(-max(rows, 1) // SUBLANE_ALIGN) * SUBLANE_ALIGN
+
+
 def clamp_block_t(block_t: int, n: int, min_tile: int) -> int:
     """Positive, contract-satisfying tile size for ANY series length.
 
     The tile never exceeds the (rounded-up) series length, never drops below
     the kernel's per-tile window requirement (``min_tile``: max_lag for the
     lag kernels, window for the moments kernel, the full reach for the
-    fused-plan megakernel), and is at least 1 — so the grid
-    ``n_pad // block_t`` is always ≥ 1, including tiny series with
-    n < max_lag and the degenerate n == 0.
+    fused-plan megakernel), and is a whole number of TPU row tiles
+    (:func:`align_rows`) — so the grid ``n_pad // block_t`` is always ≥ 1,
+    including tiny series with n < max_lag and the degenerate n == 0.
     """
-    return max(min(block_t, max(n, 1)), min_tile, 1)
+    return align_rows(max(min(block_t, max(n, 1)), min_tile))
 
 
 def pad_tiles(x: jax.Array, block_t: int, halo: int = 1) -> jax.Array:

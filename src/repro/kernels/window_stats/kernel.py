@@ -32,6 +32,11 @@ Three kernels share the tiling scheme:
 Zero-fill boundary handling: ops.py pads the series with one extra zero tile
 so the last core tile's "next" view is all zeros — out-of-range products
 vanish without any masking (the same trick the overlap data structure uses).
+
+Shifted windows are read from a VMEM scratch holding the core tile plus the
+halo (:func:`stage_rows`): the TPU lowering has no value-level dynamic
+slice, but a ref slice at any row offset (static, or ``pl.ds`` in a loop)
+lowers to a plain VMEM load.
 """
 from __future__ import annotations
 
@@ -40,13 +45,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _lag_kernel(a_core_ref, b_core_ref, b_next_ref, out_ref, *, max_lag: int, block_t: int):
+def rows_scratch(block_t: int, d: int):
+    """VMEM scratch for :func:`stage_rows`: the core tile plus its halo."""
+    return pltpu.VMEM((2 * block_t, d), jnp.float32)
+
+
+def stage_rows(core_ref, next_ref, rows_ref) -> None:
+    """Copy the core tile and the halo tile into one f32 VMEM scratch, so
+    every shifted window is a ref slice ``rows_ref[s : s + block_t]``."""
+    bt = core_ref.shape[0]
+    rows_ref[:bt, :] = core_ref[...].astype(jnp.float32)
+    rows_ref[bt:, :] = next_ref[...].astype(jnp.float32)
+
+
+def moment_sums(rows_ref, lo: int, hi: int, carry: tuple) -> tuple:
+    """Add rows ``[j, j + block_t)`` and their squares to ``carry`` for every
+    j in [lo, hi): the per-start window sums of the moments kernels."""
+    block_t = carry[0].shape[0]
+
+    def body(j, c):
+        seg = rows_ref[pl.ds(j, block_t), :]
+        return c[0] + seg, c[1] + seg * seg
+
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _lag_kernel(a_core_ref, b_core_ref, b_next_ref, out_ref, rows_ref, *, max_lag: int, block_t: int):
     i = pl.program_id(0)
 
     core = a_core_ref[...]  # (block_t, d) — the (possibly masked) left factor
-    both = jnp.concatenate([b_core_ref[...], b_next_ref[...]], axis=0)  # (2·block_t, d)
+    stage_rows(b_core_ref, b_next_ref, rows_ref)  # (2·block_t, d)
 
     @pl.when(i == 0)
     def _init():
@@ -54,11 +85,11 @@ def _lag_kernel(a_core_ref, b_core_ref, b_next_ref, out_ref, *, max_lag: int, bl
 
     # One MXU contraction per lag: every window centre of the tile at once.
     for h in range(max_lag + 1):
-        shifted = jax.lax.dynamic_slice_in_dim(both, h, block_t, axis=0)
         contrib = jax.lax.dot_general(
             core,
-            shifted,
+            rows_ref[h : h + block_t, :],
             (((0,), (0,)), ((), ())),  # contract over time: (d, d)
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
         out_ref[h, :, :] += contrib
@@ -105,6 +136,7 @@ def cross_window_stats_pallas(
         ],
         out_specs=pl.BlockSpec((max_lag + 1, d, d), lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((max_lag + 1, d, d), jnp.float32),
+        scratch_shapes=[rows_scratch(block_t, d)],
         interpret=interpret,
     )(a, b, b)
 
@@ -129,6 +161,7 @@ def _fused_kernel(
     m_core_ref,
     lag_ref,
     mom_ref,
+    rows_ref,
     *,
     max_lag: int,
     windows: tuple,
@@ -137,7 +170,7 @@ def _fused_kernel(
     i = pl.program_id(0)
 
     core = a_core_ref[...]  # (block_t, d) mask-zeroed left factor
-    both = jnp.concatenate([b_core_ref[...], b_next_ref[...]], axis=0)
+    stage_rows(b_core_ref, b_next_ref, rows_ref)
     m = m_core_ref[...]  # (block_t, 1) f32 start mask
 
     @pl.when(i == 0)
@@ -147,11 +180,11 @@ def _fused_kernel(
 
     # MXU half: one contraction per lag, every window start of the tile.
     for h in range(max_lag + 1):
-        shifted = jax.lax.dynamic_slice_in_dim(both, h, block_t, axis=0)
         lag_ref[h, :, :] += jax.lax.dot_general(
             core,
-            shifted,
+            rows_ref[h : h + block_t, :],
             (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
 
@@ -162,17 +195,11 @@ def _fused_kernel(
     # w_{k-1}'s with rows [w_{k-1}, w_k) — total work is O(max(windows)) per
     # tile whatever K is, and every window reads the same resident tile pair
     # (one HBM staging for all of them).
-    def body(j, carry):
-        acc, acc2 = carry
-        seg = jax.lax.dynamic_slice_in_dim(both, j, block_t, axis=0)
-        seg = seg.astype(jnp.float32)
-        return acc + seg, acc2 + seg * seg
-
     zeros = jnp.zeros((block_t, core.shape[1]), jnp.float32)
     carry = (zeros, zeros)
     prev_w = 0
     for k in sorted(range(len(windows)), key=lambda q: windows[q]):
-        carry = jax.lax.fori_loop(prev_w, windows[k], body, carry)
+        carry = moment_sums(rows_ref, prev_w, windows[k], carry)
         prev_w = windows[k]
         acc, acc2 = carry
         mom_ref[k, 0, :] += jnp.sum(m * acc, axis=0)
@@ -245,26 +272,20 @@ def fused_lag_moments_pallas(
             jax.ShapeDtypeStruct((max_lag + 1, d, d), jnp.float32),
             jax.ShapeDtypeStruct((K, 2, d), jnp.float32),
         ],
+        scratch_shapes=[rows_scratch(block_t, d)],
         interpret=interpret,
     )(a, b, b, m)
 
 
-def _moments_kernel(x_core_ref, x_next_ref, out_ref, *, window: int, block_t: int):
-    core = x_core_ref[...]  # (block_t, d)
-    both = jnp.concatenate([core, x_next_ref[...]], axis=0)  # (2·block_t, d)
+def _moments_kernel(x_core_ref, x_next_ref, out_ref, rows_ref, *, window: int, block_t: int):
+    stage_rows(x_core_ref, x_next_ref, rows_ref)  # (2·block_t, d)
 
     # VPU accumulation: window starts s = tile offset + [0, block_t); sample
-    # s + j lives at local row s + j of `both` (j ≤ window-1 ≤ block_t).
-    # fori_loop keeps the traced kernel body O(1) in window — a Python loop
-    # would unroll `window` slice+add pairs into the lowered program.
-    def body(j, carry):
-        acc, acc2 = carry
-        seg = jax.lax.dynamic_slice_in_dim(both, j, block_t, axis=0)
-        seg = seg.astype(jnp.float32)
-        return acc + seg, acc2 + seg * seg
-
-    zeros = jnp.zeros(core.shape, jnp.float32)
-    acc, acc2 = jax.lax.fori_loop(0, window, body, (zeros, zeros))
+    # s + j lives at local row s + j of the staged rows (j ≤ window-1 ≤
+    # block_t).  fori_loop keeps the traced kernel body O(1) in window — a
+    # Python loop would unroll `window` slice+add pairs into the program.
+    zeros = jnp.zeros(x_core_ref.shape, jnp.float32)
+    acc, acc2 = moment_sums(rows_ref, 0, window, (zeros, zeros))
     out_ref[0, :, :] = acc
     out_ref[1, :, :] = acc2
 
@@ -305,5 +326,6 @@ def window_moments_pallas(
         ],
         out_specs=pl.BlockSpec((2, block_t, d), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((2, n, d), jnp.float32),
+        scratch_shapes=[rows_scratch(block_t, d)],
         interpret=interpret,
     )(x, x)

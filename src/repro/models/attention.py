@@ -25,8 +25,7 @@ Params = Dict[str, Any]
 
 
 def _model_axis_size() -> int:
-    # version-portable active-mesh lookup (jax.sharding.get_abstract_mesh
-    # does not exist on JAX 0.4.x) — shared with the sharding-rule resolver
+    # the active-mesh lookup shared with the sharding-rule resolver
     from ..parallel.sharding import _active_mesh
 
     m = _active_mesh()

@@ -57,33 +57,9 @@ def sp_mode_enabled() -> bool:
     return _SP_MODE
 
 
-def abstract_mesh(shape: Sequence[int], axis_names: Sequence[str]):
-    """Version-portable ``AbstractMesh`` (axis names/sizes only, no devices).
-
-    Newer JAX takes ``(shape, axis_names, axis_types=...)``; 0.4.x takes a
-    single ``((name, size), ...)`` tuple.  Sharding-rule resolution only
-    reads ``mesh.shape``/``mesh.axis_names``, which both spell the same.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.sharding.AbstractMesh(
-            tuple(shape),
-            tuple(axis_names),
-            axis_types=(axis_type.Auto,) * len(tuple(axis_names)),
-        )
-    return jax.sharding.AbstractMesh(tuple(zip(axis_names, shape)))
-
-
 def _active_mesh():
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        m = get_am()
-        return None if m is None or m.empty else m
-    # JAX 0.4.x: the mesh installed by `with mesh:` lives in thread resources.
-    from jax._src import mesh as mesh_lib
-
-    m = mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m
 
 
 def mesh_axis_size(mesh, names: Sequence[str]) -> int:
@@ -117,25 +93,6 @@ def logical_to_spec(axes: Sequence[LogicalAxis], shape: Sequence[int], mesh) -> 
         else:
             entries.append(None)
     return P(*entries)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` without replication checking, across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=...)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.  Every
-    sharded estimator path goes through here so the paper's cluster scheme
-    lowers on either.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
 
 
 def psum_tree(tree: Any, axis: str) -> Any:

@@ -224,10 +224,9 @@ class TimeSeriesStore:
             local_sum = jax.tree.map(lambda l: jnp.sum(l, axis=0), partials)
             return psum_tree(local_sum, self.axis)
 
-        from ..parallel.sharding import shard_map_compat
-
-        fn = shard_map_compat(
-            local, mesh=self.mesh, in_specs=P(self.axis), out_specs=P()
+        fn = jax.shard_map(
+            local, mesh=self.mesh, in_specs=P(self.axis), out_specs=P(),
+            check_vma=False,
         )
         return fn(self.blocks)
 

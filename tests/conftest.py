@@ -11,6 +11,14 @@ import pytest  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
 
+# The one place the suite asks for Pallas interpret mode: the registered
+# "pallas" backend runs the kernels interpreted on CPU.  Registered here,
+# before any test module imports, because many modules look the backend
+# up at import time (``PALLAS = get_backend("pallas")``).
+from repro.core.backend import PallasBackend, register_backend  # noqa: E402
+
+register_backend("pallas", PallasBackend(interpret=True))
+
 
 def pytest_addoption(parser):
     parser.addoption(

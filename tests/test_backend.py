@@ -68,6 +68,15 @@ def test_register_new_backend_reaches_estimators():
     np.testing.assert_allclose(g, autocovariance(x, 3, backend="jnp"), rtol=1e-6)
 
 
+def test_pallas_backend_never_interprets_unasked():
+    """Off the TPU a compiled PallasBackend cannot exist: it raises instead
+    of silently running interpreted.  Interpret mode is opt-in."""
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        PallasBackend()
+    assert PallasBackend(interpret=True).interpret is True
+    assert PALLAS.interpret is True  # the suite's registered instance
+
+
 def test_auto_backend_is_jnp_off_tpu():
     # On CPU the "auto" policy must never route to (slow) interpret Pallas.
     x = _series(5000, 2)

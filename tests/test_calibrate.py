@@ -42,7 +42,7 @@ class _Recording(PallasBackend):
     """Pallas backend that counts which primitives were dispatched to it."""
 
     def __init__(self):
-        super().__init__()
+        super().__init__(interpret=True)
         self.calls = []
 
     def __getattribute__(self, name):
@@ -129,14 +129,31 @@ def test_cache_roundtrip_and_platform_hygiene(tmp_path, monkeypatch):
     loaded = cal.load_table()
     assert loaded is not None and loaded.source == "cache"
     assert loaded.thresholds == table.thresholds  # inf survives JSON (null)
-    # resolve_table prefers the cache over defaults and auto-measurement
+    # resolve_table prefers the installed cache over the defaults
     resolved = cal.resolve_table()
     assert resolved.thresholds == table.thresholds
     # a cache written on another platform is ignored, never misapplied
     alien = _table({p: 1.0 for p in cal.PRIMITIVES}, platform="tpu")
     cal.save_table(alien)
     assert cal.load_table() is None
-    assert cal.resolve_table(autocalibrate=False).source == "default"
+    assert cal.resolve_table().source == "default"
+
+
+def test_resolve_table_never_measures(tmp_path, monkeypatch):
+    """With no installed table, even on TPU, resolution returns the built-in
+    table and measures nothing: the first "auto" dispatch happens while a
+    served program is being traced, where a timing would time the tracer."""
+    monkeypatch.setenv("REPRO_CALIB_CACHE", str(tmp_path / "absent.json"))
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("resolve_table ran a calibration")
+
+    monkeypatch.setattr(cal, "calibrate", refuse)
+    monkeypatch.setattr(cal, "tune_blocks", refuse)
+    table = cal.resolve_table(platform="tpu")
+    assert table.source == "default" and table.platform == "tpu"
+    assert table.thresholds == cal.default_table("tpu").thresholds
+    assert cal.active_table() is table
 
 
 def test_calibrate_measures_all_primitives_and_persists(tmp_path, monkeypatch):
@@ -302,7 +319,7 @@ def test_corrupt_cache_degrades_to_defaults_with_warning(
     with pytest.warns(RuntimeWarning, match="corrupt calibration cache"):
         assert cal.load_table() is None
     with pytest.warns(RuntimeWarning):
-        resolved = cal.resolve_table(autocalibrate=False)
+        resolved = cal.resolve_table()
     assert resolved.source == "default"
     assert set(resolved.thresholds) == set(cal.PRIMITIVES)
 

@@ -64,7 +64,10 @@ def test_aliases():
 @pytest.fixture
 def mesh22():
     # AbstractMesh: sharding-rule tests need only axis names/sizes, not devices
-    return shr.abstract_mesh((2, 2), ("data", "model"))
+    return jax.sharding.AbstractMesh(
+        (2, 2), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
 
 
 def test_logical_to_spec_basic(mesh22):

@@ -17,7 +17,8 @@ def _run(code: str):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    # 8 virtual CPU devices, never the accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True,

@@ -88,9 +88,12 @@ def test_logical_spec_divisibility_fallback(dims):
     """logical_to_spec never produces a spec whose mesh axes don't divide."""
     import math
 
-    from repro.parallel.sharding import abstract_mesh, logical_to_spec, mesh_axis_size
+    from repro.parallel.sharding import logical_to_spec, mesh_axis_size
 
-    mesh = abstract_mesh((2, 2), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh(
+        (2, 2), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
     spec = logical_to_spec(["batch", "heads", "ff"][: len(dims)], dims, mesh)
     for dim, entry in zip(dims, spec):
         if entry is None:
