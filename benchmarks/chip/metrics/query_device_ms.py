@@ -1,0 +1,9 @@
+"""Read path (gather, ⊕-fold, batched finalize): device busy ms a query
+round."""
+
+
+def read(run):
+    if run.summary is None:
+        return None
+    s = run.summary.round_busy("query")
+    return None if not s else s * 1e3
