@@ -44,7 +44,6 @@ BENCH_FILES = [
     "BENCH_megakernel.json",
     "BENCH_frame.json",
     "BENCH_streaming.json",
-    "BENCH_gateway.json",
     "BENCH_chaos.json",
     "BENCH_forecast.json",
     "BENCH_integrity.json",

@@ -13,8 +13,8 @@ Two kinds of modules run here:
   (spectral primitive + fused Welch), ``bench_fused`` (N-statistic
   plans), ``bench_megakernel`` (persistent fused-plan kernel),
   ``bench_frame`` (SeriesFrame session API), ``bench_streaming``
-  (streaming monoid ingest), ``bench_gateway`` (async serving gateway),
-  ``bench_chaos`` (fault-injection overhead + breaker recovery), ``bench_forecast``
+  (streaming monoid ingest), ``bench_chaos`` (fault-injection overhead +
+  breaker recovery), ``bench_forecast``
   (served forecasts/sec + accuracy-vs-horizon), and ``bench_integrity``
   (compensated-accumulation drift + ingest-sentinel tick overhead).
 
@@ -43,7 +43,6 @@ MODULES = [
     "bench_megakernel",     # fused-plan megakernel → BENCH_megakernel.json
     "bench_frame",          # SeriesFrame session API → BENCH_frame.json
     "bench_streaming",      # streaming monoid → BENCH_streaming.json
-    "bench_gateway",        # async serving gateway → BENCH_gateway.json
     "bench_chaos",          # fault-injection overhead + breaker recovery → BENCH_chaos.json
     "bench_forecast",       # served forecasts + anomaly scoring → BENCH_forecast.json
     "bench_integrity",      # compensated drift + ingest sentinel → BENCH_integrity.json

@@ -788,10 +788,10 @@ class FrameSession(_DeferredRequests):
         # load (the gateway's per-tick coalesced query) re-traces nothing:
         # the whole multi-user read is the services' gather/⊕-fold programs
         # plus this ONE vmapped fused-finalize program.
-        self._finalize_batch = jax.jit(
-            jax.vmap(lambda states: self._plan.finalize(tuple(states),
-                                                        cache=False))
-        )
+        def finalize_batch(states):
+            return self._plan.finalize(tuple(states), cache=False)
+
+        self._finalize_batch = jax.jit(jax.vmap(finalize_batch))
 
     # -- write path ----------------------------------------------------------
     def ingest(

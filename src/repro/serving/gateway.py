@@ -20,8 +20,15 @@ What was missing is a concurrency front door.  This module is it:
     and per-tenant token-bucket rate classes refilled per tick — an
     over-rate tenant is rejected at submit with :class:`RateLimited`
     without stalling anyone else;
-  * **metrics**: p50/p99 ingest/query latency, queue depths, per-program
+  * **metrics**: p50/p99 query latency (submit → answer on the host) and
+    ingest latency (submit → acknowledgement: an ingest future resolves
+    once its scatter program is dispatched, not when the device has run
+    it; completion shows only in a profiler trace, at the end of
+    ``jit_scatter_update`` on the device), queue depths, per-program
     batch occupancy, rejected-request counters, tick-time straggler flags;
+  * **tracing**: each tick is a ``repro.tick`` step span, with one
+    ``repro.ingest.*`` / ``repro.query.*`` span per phase inside it, in
+    the profiler's trace when one is being taken (never one per request);
   * **durability**: every ``snapshot_every`` ticks the stacked session
     state (host copies — safe across donating ingests) is saved through
     `repro.checkpoint.manager.CheckpointManager`, and a restarted gateway
@@ -79,6 +86,7 @@ from typing import Any, Deque, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..core.frame import FrameSession
 from ..core.integrity import SENTINEL_POLICIES, sentinel_scan
@@ -356,7 +364,9 @@ class StatsGateway:
         return tenant
 
     def submit_ingest(self, tenant: int, chunk) -> asyncio.Future:
-        """Admit one ingest request; resolves after the absorbing tick.
+        """Admit one ingest request; resolves in the absorbing tick, once
+        its scatter program has been dispatched (an acknowledgement: the
+        device may still be running it).
 
         Raises :class:`QueueFull` / :class:`RateLimited` immediately when
         admission fails (the rejection is the backpressure signal), and
@@ -478,31 +488,32 @@ class StatsGateway:
         device programs, resolve futures, maybe snapshot.  Returns per-tick
         stats (mostly for the benchmark's narrator)."""
         async with self._tick_lock:
-            t_start = time.perf_counter()
-            shed = self._shed_if_degraded()
-            # the gateway.tick chaos site lives INSIDE the timed window: an
-            # injected stall looks exactly like a straggler device to the
-            # deadline watchdog; an injected fail is a survivable tick-level
-            # fault (counted, the tick still serves)
-            try:
-                chaos.fire("gateway.tick")
-            except Exception:
-                self.counters["tick_faults"] += 1
-            n_ing = self._run_ingests()
-            n_qry = self._run_queries()
-            tick = self._tick
-            self._tick += 1
-            dt = time.perf_counter() - t_start
-            self._update_health(tick, dt)
-            self._maybe_snapshot(tick)
-            if n_ing or n_qry:
-                self.monitor.record(tick, dt)
-            self.counters["ticks"] += 1
-            idle = self.config.bucket_idle_ticks
-            if idle and tick and tick % idle == 0:
-                evicted = self._ingest_buckets.evict_idle(tick, idle)
-                evicted += self._query_buckets.evict_idle(tick, idle)
-                self.counters["buckets_evicted"] += evicted
+            with StepTraceAnnotation("repro.tick", step_num=self._tick):
+                t_start = time.perf_counter()
+                shed = self._shed_if_degraded()
+                # the gateway.tick chaos site lives INSIDE the timed window:
+                # an injected stall looks exactly like a straggler device to
+                # the deadline watchdog; an injected fail is a survivable
+                # tick-level fault (counted, the tick still serves)
+                try:
+                    chaos.fire("gateway.tick")
+                except Exception:
+                    self.counters["tick_faults"] += 1
+                n_ing = self._run_ingests()
+                n_qry = self._run_queries()
+                tick = self._tick
+                self._tick += 1
+                dt = time.perf_counter() - t_start
+                self._update_health(tick, dt)
+                self._maybe_snapshot(tick)
+                if n_ing or n_qry:
+                    self.monitor.record(tick, dt)
+                self.counters["ticks"] += 1
+                idle = self.config.bucket_idle_ticks
+                if idle and tick and tick % idle == 0:
+                    evicted = self._ingest_buckets.evict_idle(tick, idle)
+                    evicted += self._query_buckets.evict_idle(tick, idle)
+                    self.counters["buckets_evicted"] += evicted
         # hand control back so awaiting clients observe their futures
         await asyncio.sleep(0)
         return {"tick": tick, "ingests": n_ing, "queries": n_qry,
@@ -558,37 +569,46 @@ class StatsGateway:
         tenants deferred to the next tick (a scatter must see distinct
         ids, and a tenant's chunks must land in arrival order).  With the
         sentinel enabled, each coalesced batch gets one fused all-finite
-        verdict before it can touch session state."""
-        pending = list(self._ingest_q)
-        self._ingest_q.clear()
-        carry: list = []
-        seen: set = set()
-        groups: Dict[int, list] = {}
-        for req in pending:
-            if req.tenant in self.quarantined:
-                # quarantined between admission and this tick (a carried
-                # request, or an audit() ran mid-backlog)
-                if not req.future.done():
-                    req.future.set_exception(PoisonedChunk(
-                        f"tenant {req.tenant} is quarantined; "
-                        "rebuild_tenant() restores service"
-                    ))
-                self.counters["rejected_ingest_quarantined"] += 1
-                continue
-            if req.tenant in seen:
-                carry.append(req)       # next tick: ordering + distinctness
-                continue
-            seen.add(req.tenant)
-            groups.setdefault(req.chunk.shape[0], []).append(req)
-        self._ingest_q.extend(carry)
+        verdict before it can touch session state.  Futures resolve once
+        every batch of the tick has been dispatched."""
+        if not self._ingest_q:
+            return 0
+        with TraceAnnotation("repro.ingest.stack") as span:
+            pending = list(self._ingest_q)
+            self._ingest_q.clear()
+            carry: list = []
+            seen: set = set()
+            groups: Dict[int, list] = {}
+            for req in pending:
+                if req.tenant in self.quarantined:
+                    # quarantined between admission and this tick (a
+                    # carried request, or an audit() ran mid-backlog)
+                    if not req.future.done():
+                        req.future.set_exception(PoisonedChunk(
+                            f"tenant {req.tenant} is quarantined; "
+                            "rebuild_tenant() restores service"
+                        ))
+                    self.counters["rejected_ingest_quarantined"] += 1
+                    continue
+                if req.tenant in seen:
+                    carry.append(req)   # next tick: ordering + distinctness
+                    continue
+                seen.add(req.tenant)
+                groups.setdefault(req.chunk.shape[0], []).append(req)
+            self._ingest_q.extend(carry)
+            batches = [
+                (reqs, np.asarray([r.tenant for r in reqs], np.int32),
+                 np.stack([r.chunk for r in reqs]))
+                for length, reqs in sorted(groups.items()) if length
+            ]
+            # length: the longest chunk (the only one when the tick runs
+            # one scatter program)
+            span.set_metadata(rows=sum(len(b[0]) for b in batches),
+                              length=max(groups, default=0),
+                              bytes=sum(b[2].nbytes for b in batches))
+        absorbed = list(groups.get(0, ()))  # empty chunks: no-ops, resolved too
         done = 0
-        for length, reqs in sorted(groups.items()):
-            if length == 0:
-                for r in reqs:          # empty chunk: a no-op, resolve now
-                    self._resolve(r, self._tick, self._lat_ingest)
-                continue
-            ids = np.asarray([r.tenant for r in reqs], np.int32)
-            batch: Any = np.stack([r.chunk for r in reqs])
+        for reqs, ids, batch in batches:
             if self.config.sentinel:
                 # ONE fused jitted program: per-chunk verdict + sanitized
                 # copy together; the verdict is the only host sync, and the
@@ -617,9 +637,11 @@ class StatsGateway:
             self.counters["programs_ingest"] += 1
             self._occ_ingest.append(len(reqs))
             self._dirty = True
-            for r in reqs:
-                self._resolve(r, self._tick, self._lat_ingest)
+            absorbed.extend(reqs)
             done += len(reqs)
+        with TraceAnnotation("repro.ingest.resolve", n=len(absorbed)):
+            for r in absorbed:
+                self._resolve(r, self._tick, self._lat_ingest)
         return done
 
     def _apply_sentinel(self, reqs, verdict) -> list:
@@ -679,7 +701,8 @@ class StatsGateway:
             order.setdefault(req.tenant, len(order))
         ids = np.fromiter(order.keys(), np.int32, len(order))
         try:
-            results = self.session.query_batch(ids)
+            with TraceAnnotation("repro.query.dispatch", tenants=len(order)):
+                results = self.session.query_batch(ids)
         except Exception as e:
             for r in pending:
                 if not r.future.done():
@@ -691,13 +714,16 @@ class StatsGateway:
         # ONE device→host transfer for the whole batch; per-waiter slicing
         # is then numpy views, not thousands of tiny device index dispatches
         # (results are leaving the device either way — this is the wire)
-        host = jax.device_get(results)
-        for req in pending:
-            pos = order[req.tenant]
-            value = jax.tree.map(lambda l: l[pos], host)
-            if req.only is not None:
-                value = {k: value[k] for k in req.only}
-            self._resolve(req, value, self._lat_query)
+        nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(results))
+        with TraceAnnotation("repro.query.fetch", bytes=nbytes):
+            host = jax.device_get(results)
+        with TraceAnnotation("repro.query.resolve", n=len(pending)):
+            for req in pending:
+                pos = order[req.tenant]
+                value = jax.tree.map(lambda l: l[pos], host)
+                if req.only is not None:
+                    value = {k: value[k] for k in req.only}
+                self._resolve(req, value, self._lat_query)
         return len(pending)
 
     def _resolve(self, req: _Pending, value: Any, lat: Deque[float]) -> None:
@@ -902,6 +928,9 @@ class StatsGateway:
 
     def metrics(self) -> dict:
         """The serving surface's health in one dict (latencies in µs).
+        ``query`` latencies run from submit to the answer on the host;
+        ``ingest`` latencies from submit to acknowledgement, the absorbing
+        scatter dispatched, not completed on the device.
         Rejection/snapshot counts are monotonic totals; ``window`` holds
         the same counters since the last :meth:`reset_metrics`."""
         c = self.counters

@@ -79,6 +79,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.integrity import lane_health
 from ..core.streaming import PartialState, StreamingEngine
@@ -250,11 +251,10 @@ class RollingStatsService:
                 )
             return acc
 
-        self._gather_merge = jax.jit(
-            lambda lanes, user_ids: lane_fold(
-                jax.tree.map(lambda l: l[:, user_ids], lanes)
-            )
-        )
+        def gather_merge(lanes, user_ids):
+            return lane_fold(jax.tree.map(lambda l: l[:, user_ids], lanes))
+
+        self._gather_merge = jax.jit(gather_merge)
 
     @property
     def backend(self):
@@ -459,8 +459,10 @@ class RollingStatsService:
         # the message promised a range the check didn't enforce).
         if not 0 <= shard < self.num_shards:
             raise ValueError(f"shard {shard} out of range [0, {self.num_shards})")
-        user_ids = jnp.asarray(ids, jnp.int32)
-        chunks = jnp.asarray(chunks)
+        with TraceAnnotation("repro.ingest.h2d") as span:
+            user_ids = jnp.asarray(ids, jnp.int32)
+            chunks = jnp.asarray(chunks)
+            span.set_metadata(bytes=chunks.nbytes)
         if chunks.shape[1] == 0:
             # nothing to absorb — and in eviction mode the boundary reset
             # below must not fire for an empty arrival (it would wipe a
@@ -485,23 +487,27 @@ class RollingStatsService:
                     "chunk would straddle an eviction bucket boundary; "
                     f"chunks must tile the {self.bucket_len}-sample bucket grid"
                 )
-            self._lanes = self._scatter_evict(
-                self._lanes, user_ids, chunks, jnp.asarray(starts, jnp.int32)
-            )
+            with TraceAnnotation("repro.ingest.dispatch", rows=len(ids)):
+                self._lanes = self._scatter_evict(
+                    self._lanes, user_ids, chunks,
+                    jnp.asarray(starts, jnp.int32),
+                )
         else:
             if t0 is None:
                 # update() falls back to each state's own cursor.
                 t0 = jnp.zeros(user_ids.shape, jnp.int32)
-            self._lanes = self._scatter_update(
-                self._lanes,
-                jnp.asarray(shard, jnp.int32),
-                user_ids,
-                chunks,
-                # pin the dtype: a bare asarray leaves it caller-dependent,
-                # so mixed int32/int64 t0 arrivals compiled (and cached)
-                # duplicate donated scatter programs for the same shapes
-                jnp.asarray(t0, jnp.int32),
-            )
+            with TraceAnnotation("repro.ingest.dispatch", rows=len(ids)):
+                self._lanes = self._scatter_update(
+                    self._lanes,
+                    jnp.asarray(shard, jnp.int32),
+                    user_ids,
+                    chunks,
+                    # pin the dtype: a bare asarray leaves it
+                    # caller-dependent, so mixed int32/int64 t0 arrivals
+                    # compiled (and cached) duplicate donated scatter
+                    # programs for the same shapes
+                    jnp.asarray(t0, jnp.int32),
+                )
         if self.window is not None:
             self._counts[ids] += chunks.shape[1]
 

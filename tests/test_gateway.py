@@ -436,3 +436,83 @@ def test_query_only_unknown_kind_rejected_at_submit():
         gw.submit_query(0, only="spectrum")
     with pytest.raises(ValueError, match="autocovariance"):
         gw.submit_query(0, only=("moments", "nope"))
+
+
+# ------------------------------------------- (f) the tick's profiler spans
+
+INGEST_SPANS = ("repro.ingest.stack", "repro.ingest.h2d",
+                "repro.ingest.dispatch", "repro.ingest.resolve")
+QUERY_SPANS = ("repro.query.dispatch", "repro.query.fetch",
+               "repro.query.resolve")
+
+
+def _profiled(tmp_path, coro):
+    """Run ``coro`` under the profiler; returns its result and the
+    ``repro.*`` host events as (name, start_ns, end_ns, stats)."""
+    import glob
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = run(coro)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = [
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("repro.")
+    ]
+    return out, events
+
+
+def test_each_tick_opens_one_span_per_phase_inside_repro_tick(tmp_path):
+    N, c = 3, 16
+    gw = StatsGateway(_session(N))
+    chunks = _chunks(N, c=c, seed=5)
+
+    async def ingest_then_query():
+        futs = [gw.submit_ingest(u, chunks[u]) for u in range(N)]
+        await gw.tick()
+        await asyncio.gather(*futs)
+        futs = [gw.submit_query(u) for u in range(N)]
+        await gw.tick()
+        return await asyncio.gather(*futs)
+
+    run(ingest_then_query())      # compiles outside the profile
+    answers, events = _profiled(tmp_path, ingest_then_query())
+    ticks = sorted((e for e in events if e[0] == "repro.tick"), key=lambda e: e[1])
+    assert [t[3]["step_num"] for t in ticks] == [2, 3]
+    inside = [[e for e in events if e[0] != "repro.tick" and t[1] <= e[1] and e[2] <= t[2]]
+              for t in ticks]
+    # every phase span lies inside a tick
+    assert sum(map(len, inside)) == len(events) - len(ticks)
+
+    batch = N * c * D * 4
+    ingest = {e[0]: e[3] for e in inside[0]}
+    assert sorted(e[0] for e in inside[0]) == sorted(INGEST_SPANS)
+    assert ingest["repro.ingest.stack"] == {"rows": N, "length": c, "bytes": batch}
+    assert ingest["repro.ingest.h2d"] == {"bytes": batch}
+    assert ingest["repro.ingest.dispatch"] == {"rows": N}
+    assert ingest["repro.ingest.resolve"] == {"n": N}
+
+    query = {e[0]: e[3] for e in inside[1]}
+    assert sorted(e[0] for e in inside[1]) == sorted(QUERY_SPANS)
+    assert query["repro.query.dispatch"] == {"tenants": N}
+    one = sum(np.asarray(leaf).nbytes for leaf in jax.tree.leaves(answers[0]))
+    assert query["repro.query.fetch"] == {"bytes": N * one}
+    assert query["repro.query.resolve"] == {"n": N}
+
+
+def test_read_path_programs_compile_under_stable_names():
+    N = 3
+    sess = _session(N)
+    sess.ingest(np.arange(N), np.zeros((N, 8, D), np.float32))
+    ids = jnp.arange(N, dtype=jnp.int32)
+    (svc,) = sess._services
+    assert "@jit_gather_merge" in svc._gather_merge.lower(svc._lanes, ids).as_text()
+    merged = (svc.partials_batch(ids),)
+    assert "@jit_finalize_batch" in sess._finalize_batch.lower(merged).as_text()
+    assert "@jit_scatter_update" in svc._scatter_update.lower(
+        svc._lanes, jnp.int32(0), ids, jnp.zeros((N, 8, D)),
+        jnp.zeros(N, jnp.int32)).as_text()
