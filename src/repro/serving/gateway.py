@@ -26,6 +26,11 @@ What was missing is a concurrency front door.  This module is it:
     it; completion shows only in a profiler trace, at the end of
     ``jit_scatter_update`` on the device), queue depths, per-program
     batch occupancy, rejected-request counters, tick-time straggler flags;
+  * **the answers' fetch**: a batch whose largest answer leaf reaches
+    ``_PIECE_BYTES`` leaves an accelerator in pieces below the C
+    library's mmap threshold, and malloc is told to keep freed pages, so
+    each tick's answers land in host memory that stays resident instead
+    of in pages the kernel maps and faults in anew (``_fetch``);
   * **tracing**: each tick is a ``repro.tick`` step span, with one
     ``repro.ingest.*`` / ``repro.query.*`` span per phase inside it, in
     the profiler's trace when one is being taken (never one per request);
@@ -79,8 +84,11 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import ctypes
 import dataclasses
+import functools
 import math
+import resource
 import time
 from typing import Any, Deque, Dict, Optional
 
@@ -174,6 +182,74 @@ class GatewayConfig:
     bucket_idle_ticks: int = 512           # evict buckets idle this long (0=off)
     sentinel: bool = False                 # all-finite verdict per ingest batch
     sentinel_policy: str = "reject"        # default: reject|sanitize|quarantine
+
+
+# glibc serves every block under this size from its heap (the largest
+# mmap threshold it accepts), and the host receives a large query batch's
+# answers in pieces below it; the heap keeps up to _KEEP_BYTES of freed
+# memory (mallopt's largest value), so each tick's answers land in pages
+# that earlier ticks already faulted in
+_PIECE_BYTES = 32 << 20
+_KEEP_BYTES = (1 << 31) - 1
+_M_TRIM_THRESHOLD, _M_MMAP_THRESHOLD = -1, -3
+
+
+def _minor_faults() -> int:
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+
+def _row_major(leaf: jax.Array) -> bool:
+    """Whether the device holds ``leaf`` in the host's own layout."""
+    layout = leaf.format.layout
+    return (not layout.tiling
+            and tuple(layout.major_to_minor) == tuple(range(leaf.ndim)))
+
+
+@functools.cache
+def _keep_freed_pages() -> bool:
+    """Make the C library's malloc keep the pages of freed blocks below
+    ``_PIECE_BYTES`` instead of returning them to the kernel.  Process-wide
+    and set once; False where the C library has no ``mallopt``."""
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return False
+    mallopt.argtypes = (ctypes.c_int, ctypes.c_int)
+    mallopt.restype = ctypes.c_int
+    return bool(mallopt(_M_MMAP_THRESHOLD, _PIECE_BYTES)
+                and mallopt(_M_TRIM_THRESHOLD, _KEEP_BYTES))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def answer_pieces(leaves: list, bounds: tuple) -> list:
+    """Every answer leaf cut along its tenant axis at ``bounds``."""
+    return [[leaf[lo:hi] for leaf in leaves]
+            for lo, hi in zip(bounds, bounds[1:])]
+
+
+def _fetch(results) -> tuple:
+    """Copy one query batch's answers (leading tenant axis) to the host.
+
+    Returns ``(path, size, pieces)``: ``pieces[j]`` is the tree of answers
+    of tenants ``j * size`` to ``(j + 1) * size``.  A ``jax.device_get`` of
+    the batch puts each leaf in a fresh host allocation, and a leaf of
+    ``_PIECE_BYTES`` or more is mapped anew from the kernel every tick:
+    the copy then pays a first-touch fault on every page, which on a TPU
+    host costs many times the copy itself.  So where a leaf is that large
+    and the device does not hold it in the host's layout (where it does,
+    the CPU, ``device_get`` copies nothing), the device cuts the batch into
+    pieces below ``_PIECE_BYTES``, which the host's heap serves from pages
+    it keeps.
+    """
+    leaves, treedef = jax.tree.flatten(results)
+    n = leaves[0].shape[0]
+    # a sixteenth of room below the threshold for the allocator's rounding
+    size = max(1, _PIECE_BYTES * 15 // 16 // max(leaf.nbytes // n for leaf in leaves))
+    if size >= n or all(map(_row_major, leaves)) or not _keep_freed_pages():
+        return "device_get", n, [jax.device_get(results)]
+    bounds = tuple(range(0, n, size)) + (n,)
+    host = jax.device_get(answer_pieces(leaves, bounds))
+    return "pieces", size, [treedef.unflatten(piece) for piece in host]
 
 
 def _event_loop() -> asyncio.AbstractEventLoop:
@@ -711,16 +787,19 @@ class StatsGateway:
             return 0
         self.counters["programs_finalize"] += 1
         self._occ_query.append(len(order))
-        # ONE device→host transfer for the whole batch; per-waiter slicing
+        # the whole batch leaves the device in one fetch; per-waiter slicing
         # is then numpy views, not thousands of tiny device index dispatches
-        # (results are leaving the device either way — this is the wire)
         nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(results))
-        with TraceAnnotation("repro.query.fetch", bytes=nbytes):
-            host = jax.device_get(results)
+        with TraceAnnotation("repro.query.fetch", bytes=nbytes) as span:
+            faults = _minor_faults()
+            path, size, pieces = _fetch(results)
+            span.set_metadata(minflt=_minor_faults() - faults, path=path,
+                              pieces=len(pieces))
         with TraceAnnotation("repro.query.resolve", n=len(pending)):
             for req in pending:
                 pos = order[req.tenant]
-                value = jax.tree.map(lambda l: l[pos], host)
+                host = pieces[pos // size]
+                value = jax.tree.map(lambda l: l[pos % size], host)
                 if req.only is not None:
                     value = {k: value[k] for k in req.only}
                 self._resolve(req, value, self._lat_query)

@@ -10,6 +10,7 @@ Pins the three serving contracts:
     without stalling other tenants.
 """
 import asyncio
+import types
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ import pytest
 
 from repro.core.backend import get_backend
 from repro.core.frame import FrameSession, SeriesFrame
+from repro.serving import gateway
 from repro.serving.gateway import (
     GatewayConfig,
     QueueFull,
@@ -500,7 +502,9 @@ def test_each_tick_opens_one_span_per_phase_inside_repro_tick(tmp_path):
     assert sorted(e[0] for e in inside[1]) == sorted(QUERY_SPANS)
     assert query["repro.query.dispatch"] == {"tenants": N}
     one = sum(np.asarray(leaf).nbytes for leaf in jax.tree.leaves(answers[0]))
-    assert query["repro.query.fetch"] == {"bytes": N * one}
+    fetch = dict(query["repro.query.fetch"])
+    assert fetch.pop("minflt") >= 0
+    assert fetch == {"bytes": N * one, "path": "device_get", "pieces": 1}
     assert query["repro.query.resolve"] == {"n": N}
 
 
@@ -516,3 +520,158 @@ def test_read_path_programs_compile_under_stable_names():
     assert "@jit_scatter_update" in svc._scatter_update.lower(
         svc._lanes, jnp.int32(0), ids, jnp.zeros((N, 8, D)),
         jnp.zeros(N, jnp.int32)).as_text()
+
+
+# ------------------------------------------------- (g) the answers' fetch
+
+
+def _tenant_minor(monkeypatch, sess, piece_bytes):
+    """Make ``sess`` hand its batched answers over with the tenant axis
+    minor, as a TPU keeps them, and the gateway cut batches whose leaves
+    reach ``piece_bytes`` into pieces below it."""
+    from jax.experimental.layout import Format, Layout
+
+    sess._ensure_plan()
+    finalize = sess._finalize_batch
+
+    def tenant_minor(merged):
+        return jax.tree.map(
+            lambda leaf: jax.device_put(leaf, Format(
+                Layout(tuple(range(1, leaf.ndim)) + (0,)), leaf.sharding)),
+            finalize(merged))
+
+    monkeypatch.setattr(sess, "_finalize_batch", tenant_minor)
+    monkeypatch.setattr(gateway, "_PIECE_BYTES", piece_bytes)
+    return sess
+
+
+def _fetches(monkeypatch):
+    """Record the path and piece count of every fetch."""
+    seen = []
+    fetch = gateway._fetch
+
+    def recording(results):
+        path, size, pieces = fetch(results)
+        seen.append((path, len(pieces)))
+        return path, size, pieces
+
+    monkeypatch.setattr(gateway, "_fetch", recording)
+    return seen
+
+
+def _bits(tree):
+    return [(leaf.dtype, leaf.shape, np.asarray(leaf).tobytes())
+            for leaf in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("hold", [True, False], ids=["held", "dropped"])
+@pytest.mark.parametrize("layout", ["row_major", "tenant_minor"])
+def test_resolved_answers_stay_bit_identical_over_later_ticks(
+        monkeypatch, layout, hold):
+    N = 5
+    sess = _session(N)
+    if layout == "tenant_minor":
+        # 64-byte lag leaves a tenant: pieces of 2, 2 and 1 tenants
+        _tenant_minor(monkeypatch, sess, piece_bytes=150)
+    seen = _fetches(monkeypatch)
+    gw = StatsGateway(sess)
+    held = []
+
+    async def round_(seed):
+        chunks = _chunks(N, c=24, seed=seed)
+        futs = [gw.submit_ingest(u, chunks[u]) for u in range(N)]
+        await gw.tick()
+        await asyncio.gather(*futs)
+        futs = [gw.submit_query(u) for u in range(N)]
+        await gw.tick()
+        return await asyncio.gather(*futs)
+
+    first = run(round_(0))
+    want = [_bits(a) for a in first]
+    for k in range(1, 4):
+        later = run(round_(k))
+        assert [_bits(a) for a in later] != want
+        if hold:
+            held.append(later)
+        del later
+        # the later ticks' answers landed in freed and reused host memory;
+        # the tick-0 answers kept every bit
+        assert [_bits(a) for a in first] == want
+    assert set(seen) == ({("pieces", 3)} if layout == "tenant_minor"
+                         else {("device_get", 1)})
+
+
+@pytest.mark.parametrize("piece_bytes", [1, 200, 1 << 30],
+                         ids=["one_tenant", "short_last", "whole"])
+def test_pieces_match_device_get_for_a_mixed_plan(monkeypatch, piece_bytes):
+    N = 7
+
+    def mixed():
+        sess = _session(N)
+        sess.welch(16, overlap=8)
+        sess.forecast(4, model="ar", p=2)
+        return sess
+
+    chunks = _chunks(N, c=48, seed=21)
+    only = [None, "forecast", ("moments", "welch"), None, "autocovariance",
+            ("forecast", "welch", "moments"), None]
+
+    async def serve(gw):
+        futs = [gw.submit_ingest(u, chunks[u]) for u in range(N)]
+        await gw.tick()
+        await asyncio.gather(*futs)
+        # tenant 3 twice and tenant 0 last: waiters out of tenant order
+        order = [3, 1, 3, 6, 2, 5, 4, 0]
+        futs = [gw.submit_query(u, only=only[u]) for u in order]
+        await gw.tick()
+        return order, await asyncio.gather(*futs)
+
+    plain = mixed()
+    order, _ = run(serve(StatsGateway(plain)))
+    ids = list(dict.fromkeys(order))
+    direct = jax.device_get(plain.query_batch(np.asarray(ids, np.int32)))
+
+    seen = _fetches(monkeypatch)
+    sess = _tenant_minor(monkeypatch, mixed(), piece_bytes)
+    order, answers = run(serve(StatsGateway(sess)))
+    # the largest leaf, Welch's PSD, has 72 bytes a tenant: pieces of one
+    # tenant, and of two
+    assert seen == [{1: ("pieces", 7), 200: ("pieces", 4),
+                     1 << 30: ("device_get", 1)}[piece_bytes]]
+    for u, got in zip(order, answers):
+        want = jax.tree.map(lambda leaf: leaf[ids.index(u)], direct)
+        if only[u] is not None:
+            kinds = (only[u],) if isinstance(only[u], str) else only[u]
+            want = {k: want[k] for k in kinds}
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        assert _bits(got) == _bits(want)
+
+
+def test_fetch_path_follows_the_device_layout(monkeypatch):
+    from jax.experimental.layout import Format, Layout
+
+    x = jnp.arange(24.0).reshape(2, 3, 4)
+    assert gateway._row_major(x)
+    assert gateway._row_major(jnp.arange(3.0))
+    minor = jax.device_put(x, Format(Layout((1, 2, 0)), x.sharding))
+    assert not gateway._row_major(minor)
+    # a TPU's f32[n, d]: row-major order, in (8, 128) tiles
+    tiled = types.SimpleNamespace(ndim=2, format=types.SimpleNamespace(
+        layout=Layout((0, 1), tiling=((8, 128),))))
+    assert not gateway._row_major(tiled)
+
+    # leaves of 48 bytes a tenant; a piece is one tenant below 60 bytes
+    batch = {"a": minor, "b": jnp.arange(2.0)}
+    assert gateway._fetch(batch)[:2] == ("device_get", 2)
+    monkeypatch.setattr(gateway, "_PIECE_BYTES", 60)
+    assert gateway._keep_freed_pages()
+    path, size, pieces = gateway._fetch(batch)
+    assert (path, size, len(pieces)) == ("pieces", 1, 2)
+    for j, piece in enumerate(pieces):
+        np.testing.assert_array_equal(piece["a"], np.asarray(x)[j:j + 1])
+        np.testing.assert_array_equal(piece["b"], [float(j)])
+    # the host's own layout copies nothing: no pieces
+    assert gateway._fetch({"a": x})[:2] == ("device_get", 2)
+    # nor where the C library cannot keep freed pages
+    monkeypatch.setattr(gateway, "_keep_freed_pages", lambda: False)
+    assert gateway._fetch(batch)[:2] == ("device_get", 2)
