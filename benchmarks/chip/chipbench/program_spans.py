@@ -11,8 +11,8 @@ Its device programs have stable names (``jit_scatter_update``,
 each device plane's ``XLA Modules`` line, with a ``(<id>)`` suffix.
 
 `reduce` reads everything `chipbench.tracing.reduce` reads, unchanged, and
-keeps besides the ``repro.*`` host events and, for each device, the
-intervals of each ``XLA Modules`` event keyed by its module name.  A
+keeps besides the ``repro.*`` host events and, for each chip of the cell,
+the intervals of each ``XLA Modules`` event keyed by its module name.  A
 `ProgramSummary` answers every question a `tracing.Summary` answers, adds
 readers of the program's spans and modules, and gives each idle stretch
 of the device to the innermost host span that covers it.  On a trace with
@@ -40,12 +40,17 @@ def module_name(event_name: str) -> str:
 class ProgramSummary(tracing.Summary):
     """A `tracing.Summary` with the program's spans and modules."""
 
+    HOST_PREFIXES = (tracing.SPAN_PREFIX, PROGRAM_PREFIX)
+
     def __init__(self, spans: list, device_ops: dict, busy_by_device: dict,
-                 program_spans: list, modules: dict):
-        super().__init__(spans, device_ops, busy_by_device)
+                 chips: int, program_spans: list, modules: dict):
+        super().__init__(spans, device_ops, busy_by_device, chips)
         self.program_spans = sorted(program_spans, key=lambda s: s.start)
         self._program_starts = [s.start for s in self.program_spans]
         self.modules = modules     # device -> module -> merged intervals
+        self._all = sorted(self._inner + self.program_spans, key=lambda s: s.start)
+        self._all_starts = [s.start for s in self._all]
+        self._all_longest = max((s.seconds for s in self._all), default=0.0)
 
     # ---------------------------------------------------------- the rounds
     def _program_in(self, r, name: Optional[str] = None) -> list:
@@ -91,56 +96,46 @@ class ProgramSummary(tracing.Summary):
 
     def module_busy(self, kind: str, module: str) -> Optional[float]:
         """Mean device busy seconds per round of ``kind`` in program
-        ``module`` (its ``XLA Modules`` events), averaged over the devices
-        that ran it; ``None`` where no device ran it."""
+        ``module`` (its ``XLA Modules`` events), per chip of the cell;
+        ``None`` where no chip ran it."""
         rs = self.of_kind(kind)
         runs = [m[module] for m in self.modules.values() if module in m]
         if not rs or not runs:
             return None
         return sum(tracing.covered(iv, r.start, r.end) for iv in runs for r in rs) \
-            / (len(rs) * len(runs))
+            / (len(rs) * self.chips)
 
     # ------------------------------------------------------------ breakdown
-    def breakdown(self, top: int = 10) -> dict:
-        """`tracing.Summary.breakdown`, with each idle stretch given to the
-        innermost host span that covers it (the one that started last), so
-        a gap inside ``bench.tick`` reads ``host in repro.query.fetch``.
-        The ``bench.*`` spans never nest within each other, so without
-        program spans this is `tracing.Summary.breakdown`."""
-        out = super().breakdown(top)
-        lo, hi = self.window
-        spans = sorted(self._inner + self.program_spans, key=lambda s: s.start)
-        starts = [s.start for s in spans]
-        longest = max((s.seconds for s in spans), default=0.0)
-        idle: dict = {}
-        merged = next(iter(self.busy_by_device.values()), [])
-        for g0, g1 in tracing.gaps(merged, lo, hi):
-            first = bisect.bisect_left(starts, g0 - longest)
-            last = bisect.bisect_left(starts, g1)
-            near = [s for s in spans[first:last] if s.end > g0]
-            cuts = sorted({g0, g1} | {t for s in near for t in (s.start, s.end)
-                                      if g0 < t < g1})
-            for a, b in zip(cuts, cuts[1:]):
-                inner = None
-                for s in near:   # sorted by start: the last cover is innermost
-                    if s.start <= a and s.end >= b:
-                        inner = s
-                name = inner.name if inner else "outside any span"
-                idle[name] = idle.get(name, 0.0) + (b - a)
-        gap_list = sorted(idle.items(), key=lambda kv: -kv[1])[:top]
-        out["idle_gaps"] = [
-            [f"host in {n}" if n.startswith((tracing.SPAN_PREFIX, PROGRAM_PREFIX))
-             else n, s] for n, s in gap_list]
-        return out
+    def split_gap(self, g0: float, g1: float, idle: dict) -> None:
+        """Give each part of the idle stretch [g0, g1) to the innermost host
+        span that covers it (the one that started last), so a gap inside
+        ``bench.tick`` reads ``host in repro.query.fetch``.  The
+        ``bench.*`` spans never nest within each other, so without program
+        spans this is `tracing.Summary.split_gap`."""
+        first = bisect.bisect_left(self._all_starts, g0 - self._all_longest)
+        last = bisect.bisect_left(self._all_starts, g1)
+        near = [s for s in self._all[first:last] if s.end > g0]
+        cuts = sorted({g0, g1} | {t for s in near for t in (s.start, s.end)
+                                  if g0 < t < g1})
+        for a, b in zip(cuts, cuts[1:]):
+            inner = None
+            for s in near:   # sorted by start: the last cover is innermost
+                if s.start <= a and s.end >= b:
+                    inner = s
+            name = inner.name if inner else "outside any span"
+            idle[name] = idle.get(name, 0.0) + (b - a)
 
 
-def reduce(profile) -> ProgramSummary:
+def reduce(profile, chips: int) -> ProgramSummary:
     """`tracing.reduce`, keeping besides the ``repro.*`` host events and the
-    ``XLA Modules`` intervals of each device."""
-    base = tracing.reduce(profile)
+    ``XLA Modules`` intervals of each chip of the cell."""
+    base = tracing.reduce(profile, chips)
     program, modules = [], {}
+    cell = tracing.chip_planes(chips)
     for plane in profile.planes:
         if plane.name.startswith(tracing.DEVICE_PLANE_PREFIX):
+            if plane.name not in cell:
+                continue
             by_module: dict = {}
             for line in plane.lines:
                 if line.name != MODULE_LINE:
@@ -160,4 +155,4 @@ def reduce(profile) -> ProgramSummary:
                         program.append(tracing.Span(
                             ev.name, s, s + ev.duration_ns * 1e-9, dict(ev.stats)))
     return ProgramSummary(base.spans, base.device_ops, base.busy_by_device,
-                          program, modules)
+                          chips, program, modules)

@@ -34,6 +34,7 @@ class Run:
 
     config: dict
     traffic: dict
+    chips: int              # the cell's chips, as `BENCHMARK.json` gives them
     setup_s: float
     rounds: list            # window rounds (`chipbench.traffic.Round`)
     t_start: float
@@ -58,13 +59,15 @@ def configure_cache() -> None:
 
 
 def build(config: dict):
-    """The served path as the configuration states it."""
+    """The served path as the configuration states it: its ``session``
+    object, where it has one, gives `FrameSession` further keywords."""
     from repro import FrameSession
     from repro.serving.gateway import GatewayConfig, StatsGateway
 
     session = FrameSession(d=config["metrics"], num_users=config["hosts"],
                            backend=config["backend"],
-                           compensated=config["compensated"])
+                           compensated=config["compensated"],
+                           **config.get("session", {}))
     names = [spec.member(m["kind"]).declare(session, m["params"])
              for m in config["plan"]]
     return session, StatsGateway(session, GatewayConfig(**config["gateway"])), names
@@ -78,7 +81,7 @@ async def _drive(driver, traffic, seconds, trace, t_process, watch, gw, chips):
     await driver.run_phase(traffic["setup"])
     setup_s = time.perf_counter() - t_process
     before = (collections.Counter(watch.by_event), watch.seconds, dict(gw.counters))
-    with tracing.capture(trace) as captured:
+    with tracing.capture(trace, chips) as captured:
         rounds, t_start, t_end = await driver.window(traffic["window"], seconds)
     events = watch.by_event - before[0]
     log(f"compiles in window: {events[BACKEND_COMPILE]} backend compiles; "
@@ -116,7 +119,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
 
     peak = device.peaks(dev["kind"]) if dev["platform"] == "tpu" else None
-    run = Run(config, traffic, setup_s, rounds, t_start, t_end, summary, peak)
+    run = Run(config, traffic, cell.chips, setup_s, rounds, t_start, t_end,
+              summary, peak)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         group = "metrics" if trace else "endtoend"

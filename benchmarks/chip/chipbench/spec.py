@@ -5,6 +5,9 @@ configuration, traffic mix, limit file and metric reader is a file of its
 own under ``benchmarks/chip``:
 
 - ``configs/<config>.json``: the deployment (sizes, plan, guarantees);
+  its optional ``session`` object holds further keyword arguments of
+  `FrameSession` (such as ``num_shards``), passed beside ``d``,
+  ``num_users``, ``backend`` and ``compensated`` (`chipbench.run.build`);
 - ``traffic/<traffic>.json``: the mix, read by `chipbench.traffic`;
 - ``limits/<workload>.json``: the limit of each number compared;
 - ``endtoend/<metric>.py`` and ``metrics/<metric>.py``: one reader each,

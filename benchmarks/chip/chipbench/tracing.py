@@ -5,9 +5,12 @@ The benchmark's own host spans (``bench.round`` around each round, with
 inside) are `jax.profiler.TraceAnnotation`s, so they land in the same trace
 as the device's operations and on the same clock.
 
-Device busy time is the union of the intervals in which an operation ran
-on a TPU (the ``XLA Ops`` line of each ``/device:TPU:<n>`` plane, or
-``XLA Modules`` where a plane has no op line).  Device time is given to
+A cell of N chips reads the planes ``/device:TPU:0`` … ``N−1``; planes of
+other chips on the host are ignored.  A chip's busy time is the union of
+the intervals in which an operation ran on it (its ``XLA Ops`` line, or
+``XLA Modules`` where the plane has no op line).  Busy and idle time are
+per chip of the cell: the sum over its N chips over N, a chip with no
+events counting as idle for the whole window.  Device time is given to
 rounds by where it lies, not by program name: round i owns the device
 time between its start and the next round's start (the last round up to
 the end of the drain), since the gateway dispatches without waiting.
@@ -26,6 +29,11 @@ from typing import Iterable, Optional
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OP_LINES = ("XLA Ops", "XLA Modules")
 SPAN_PREFIX = "bench."
+
+
+def chip_planes(chips: int) -> tuple:
+    """The device planes of a cell of ``chips`` chips."""
+    return tuple(f"{DEVICE_PLANE_PREFIX}{i}" for i in range(chips))
 
 
 # ---------------------------------------------------------------- intervals
@@ -94,16 +102,22 @@ class TraceRound:
 
 
 class Summary:
-    """What the per-layer readers read: rounds, host spans, device busy."""
+    """What the per-layer readers read: rounds, host spans, device busy
+    per chip of a cell of ``chips`` chips."""
 
-    def __init__(self, spans: list, device_ops: dict, busy_by_device: dict):
+    HOST_PREFIXES = (SPAN_PREFIX,)   # spans an idle stretch is given to
+
+    def __init__(self, spans: list, device_ops: dict, busy_by_device: dict,
+                 chips: int):
         self.spans = sorted(spans, key=lambda s: s.start)
         self.device_ops = device_ops              # (module, op) -> seconds
         self.busy_by_device = busy_by_device      # device -> merged intervals
+        self.chips = chips
         rounds = [s for s in self.spans if s.name == SPAN_PREFIX + "round"]
         drains = [s for s in self.spans if s.name == SPAN_PREFIX + "drain"]
         self._inner = [s for s in self.spans if s.name != SPAN_PREFIX + "round"]
         self._inner_starts = [s.start for s in self._inner]
+        self._longest = max((s.seconds for s in self._inner), default=0.0)
         self.rounds: list = []
         for i, r in enumerate(rounds):
             if i + 1 < len(rounds):
@@ -122,12 +136,9 @@ class Summary:
                        if self.rounds else None)
 
     def busy(self, lo: float, hi: float) -> float:
-        """Device busy seconds in [lo, hi), averaged over the devices."""
-        if not self.busy_by_device:
-            return 0.0
+        """Device busy seconds in [lo, hi) per chip of the cell."""
         return sum(covered(m, lo, hi) for m in self.busy_by_device.values()) \
-            / len(self.busy_by_device)
-
+            / self.chips
     def of_kind(self, kind: str) -> list:
         return [r for r in self.rounds if r.kind == kind]
 
@@ -156,42 +167,51 @@ class Summary:
         lo, hi = self.window
         return self.busy(lo, hi)
 
+    def split_gap(self, g0: float, g1: float, idle: dict) -> None:
+        """Add the idle stretch [g0, g1) to ``idle`` by the host span it
+        overlaps (the rest is "outside any span")."""
+        rest = g1 - g0
+        # spans that can overlap the gap start within the longest before it
+        first = bisect.bisect_left(self._inner_starts, g0 - self._longest)
+        last = bisect.bisect_left(self._inner_starts, g1)
+        for s in self._inner[first:last]:
+            overlap = min(s.end, g1) - max(s.start, g0)
+            if overlap > 0:
+                idle[s.name] = idle.get(s.name, 0.0) + overlap
+                rest -= overlap
+        if rest > 0:
+            idle["outside any span"] = idle.get("outside any span", 0.0) + rest
+
     def breakdown(self, top: int = 10) -> dict:
-        """The device operations that took most time, and the device's idle
-        time in the window split by the host span it overlaps (the rest is
-        "outside any span")."""
+        """The device operations that took most time, and the idle
+        chip-seconds of the window, summed over the cell's chips and split
+        by `split_gap`."""
         ops = sorted(self.device_ops.items(), key=lambda kv: -kv[1])[:top]
         lo, hi = self.window
-        longest = max((s.seconds for s in self._inner), default=0.0)
         idle: dict = {}
-        merged = next(iter(self.busy_by_device.values()), [])
-        for g0, g1 in gaps(merged, lo, hi):
-            rest = g1 - g0
-            # spans that can overlap the gap start within `longest` before it
-            first = bisect.bisect_left(self._inner_starts, g0 - longest)
-            last = bisect.bisect_left(self._inner_starts, g1)
-            for s in self._inner[first:last]:
-                overlap = min(s.end, g1) - max(s.start, g0)
-                if overlap > 0:
-                    idle[s.name] = idle.get(s.name, 0.0) + overlap
-                    rest -= overlap
-            if rest > 0:
-                idle["outside any span"] = idle.get("outside any span", 0.0) + rest
+        for plane in chip_planes(self.chips):   # a chip with no events: all idle
+            for g0, g1 in gaps(self.busy_by_device.get(plane, []), lo, hi):
+                self.split_gap(g0, g1, idle)
         gap_list = sorted(idle.items(), key=lambda kv: -kv[1])[:top]
         return {
             "device_ops": [[f"{m}/{o}" if m else o, s] for (m, o), s in ops],
-            "idle_gaps": [[f"host in {n}" if n.startswith(SPAN_PREFIX) else n, s]
-                          for n, s in gap_list],
+            "idle_gaps": [
+                [f"host in {n}" if n.startswith(self.HOST_PREFIXES) else n, s]
+                for n, s in gap_list],
         }
 
 
-def reduce(profile) -> Summary:
+def reduce(profile, chips: int) -> Summary:
     """Reduce a `jax.profiler.ProfileData` (or anything with its
-    ``planes`` → ``lines`` → ``events`` shape) to a `Summary`."""
+    ``planes`` → ``lines`` → ``events`` shape) to the `Summary` of a cell
+    of ``chips`` chips."""
     spans, device_ops, busy = [], {}, {}
+    cell = chip_planes(chips)
     for plane in profile.planes:
         lines = list(plane.lines)
         if plane.name.startswith(DEVICE_PLANE_PREFIX):
+            if plane.name not in cell:
+                continue
             by_name = {line.name: line for line in lines}
             line = next((by_name[n] for n in OP_LINES if n in by_name), None)
             if line is None:
@@ -213,14 +233,14 @@ def reduce(profile) -> Summary:
                         s = ev.start_ns * 1e-9
                         spans.append(Span(ev.name, s, s + ev.duration_ns * 1e-9,
                                           dict(ev.stats)))
-    return Summary(spans, device_ops, busy)
+    return Summary(spans, device_ops, busy, chips)
 
 
 @contextlib.contextmanager
-def capture(enabled: bool):
+def capture(enabled: bool, chips: int):
     """Profile the body when ``enabled``; yields a holder whose ``summary``
-    is set once the body has run.  The trace goes to a temporary directory
-    that is removed after it has been read."""
+    (of a cell of ``chips`` chips) is set once the body has run.  The trace
+    goes to a temporary directory that is removed after it has been read."""
     holder = type("Captured", (), {"summary": None})()
     if not enabled:
         yield holder
@@ -239,6 +259,7 @@ def capture(enabled: bool):
             jax.profiler.stop_trace()
         files = glob.glob(os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb"))
         if files:
-            holder.summary = reduce(jax.profiler.ProfileData.from_file(max(files)))
+            holder.summary = reduce(jax.profiler.ProfileData.from_file(max(files)),
+                                    chips)
     finally:
         shutil.rmtree(log_dir, ignore_errors=True)

@@ -1,5 +1,6 @@
-"""Tenant state and plan update: device busy ms an ingest round (the
-device time that lies in the round's share of the trace)."""
+"""Tenant state and plan update: device busy ms an ingest round per chip
+of the cell (the device time that lies in the round's share of the
+trace)."""
 
 
 def read(run):

@@ -1,5 +1,5 @@
 """Read path (gather, ⊕-fold, batched finalize): device busy ms a query
-round."""
+round per chip of the cell."""
 
 
 def read(run):

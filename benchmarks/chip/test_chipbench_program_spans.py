@@ -22,7 +22,7 @@ DEVICE_METRICS = ["result_transfer_ms.query", "gather_device_ms.query",
 
 
 def _read(summary, name, config=None):
-    run = types.SimpleNamespace(summary=summary, config=config,
+    run = types.SimpleNamespace(summary=summary, config=config, chips=summary.chips,
                                 peak=device.peaks("TPU v5 lite"))
     return spec.reader("metrics", name).read(run)
 
@@ -85,11 +85,12 @@ def test_module_names_drop_the_chips_id_suffix():
     ("finalize_device_ms.query", 1500.0),
 ])
 def test_each_new_metric_reads_the_chip_form_trace(name, want):
-    assert _read(program_spans.reduce(_chip_trace()), name) == pytest.approx(want)
+    s = program_spans.reduce(_chip_trace(), chips=1)
+    assert _read(s, name) == pytest.approx(want)
 
 
 def test_chip_form_trace_keeps_the_existing_readings():
-    s = program_spans.reduce(_chip_trace())
+    s = program_spans.reduce(_chip_trace(), chips=1)
     assert [r.kind for r in s.rounds] == ["ingest", "query"]
     assert _read(s, "ingest_device_ms") == pytest.approx(4000.0)
     # the two read-path programs are the query round's device time
@@ -101,8 +102,18 @@ def test_chip_form_trace_keeps_the_existing_readings():
         {"fusion.43": 4.0, "copy.2": 1.5, "fusion.1": 1.0})
 
 
+def test_module_time_is_per_chip_of_the_cell():
+    """Only TPU:0 runs the programs: read as a four-chip cell, each device
+    time is a quarter of the one-chip reading."""
+    one = program_spans.reduce(_chip_trace(), chips=1)
+    four = program_spans.reduce(_chip_trace(), chips=4)
+    for name in ("gather_device_ms.query", "finalize_device_ms.query",
+                 "query_device_ms", "ingest_device_ms"):
+        assert _read(four, name) == pytest.approx(_read(one, name) / 4), name
+
+
 def test_idle_gaps_go_to_the_innermost_span():
-    b = program_spans.reduce(_chip_trace()).breakdown(top=20)
+    b = program_spans.reduce(_chip_trace(), chips=1).breakdown(top=20)
     # gaps 0–5.5, 9.5–12.2 and 14.7–21
     assert dict(b["idle_gaps"]) == pytest.approx({
         "host in bench.submit": 2 + 1,
@@ -128,7 +139,8 @@ def _per_layer_names():
 def test_a_trace_without_program_spans_reads_as_before():
     """The benchmark's own synthetic trace: the same rounds, breakdown and
     per-layer readings as `tracing.reduce` gives, and no new reading."""
-    old, new = tracing.reduce(_trace()), program_spans.reduce(_trace())
+    old = tracing.reduce(_trace(), chips=1)
+    new = program_spans.reduce(_trace(), chips=1)
     assert new.rounds == old.rounds and new.window == old.window
     b_old, b_new = old.breakdown(), new.breakdown()
     assert b_new["device_ops"] == b_old["device_ops"]
@@ -173,7 +185,7 @@ def test_span_metrics_read_a_real_gateway_tick_on_the_cpu(tmp_path):
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
-    s = program_spans.reduce(jax.profiler.ProfileData.from_file(path))
+    s = program_spans.reduce(jax.profiler.ProfileData.from_file(path), chips=1)
     assert [r.kind for r in s.rounds] == ["ingest", "query"]
     for name in SPAN_METRICS:
         assert _read(s, name) > 0, name

@@ -4,6 +4,7 @@ shape of ``BENCHMARK.json``."""
 import json
 import os
 import re
+import types
 
 import numpy as np
 import pytest
@@ -72,7 +73,7 @@ def _trace():
 
 
 def test_reduce_rounds_busy_and_idle():
-    s = tracing.reduce(_trace())
+    s = tracing.reduce(_trace(), chips=1)
     assert [r.kind for r in s.rounds] == ["ingest", "ingest"]
     assert s.window == (0.0, 22.0)
     assert list(s.busy_by_device) == ["/device:TPU:0"]   # TPU:1 did nothing
@@ -86,7 +87,7 @@ def test_reduce_rounds_busy_and_idle():
 
 
 def test_breakdown_names_ops_and_what_the_host_did_in_each_gap():
-    b = tracing.reduce(_trace()).breakdown()
+    b = tracing.reduce(_trace(), chips=1).breakdown()
     assert b["device_ops"][0] == ["jit_scatter/copy.2", pytest.approx(6.0)]
     assert b["device_ops"][1] == ["jit_scatter/fusion.1", pytest.approx(5.0)]
     # gaps 0–5, 8–12, 14–15 and 21–22, split by the host span they overlap
@@ -98,6 +99,93 @@ def test_breakdown_names_ops_and_what_the_host_did_in_each_gap():
     }
 
 
+# ------------------------------------------------------- a cell of N chips
+def _four_chip_trace(working):
+    """`_trace`'s host on a host of four chips: each chip in ``working``
+    runs TPU:0's operations of `_trace`, the others none."""
+    host, work_plane = _trace().planes[:2]
+    return _Profile([host] + [
+        _Plane(f"/device:TPU:{i}",
+               work_plane.lines if i in working else [_Line("XLA Ops", [])])
+        for i in range(4)])
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_a_cell_reads_busy_and_idle_per_chip(chips):
+    """Only TPU:0 works on a host of four.  Read as a one-chip cell, the
+    numbers of `test_reduce_rounds_busy_and_idle`; read as a four-chip
+    cell, each idle chip counts as idle for the whole window."""
+    s = tracing.reduce(_four_chip_trace(working=(0,)), chips=chips)
+    assert list(s.busy_by_device) == ["/device:TPU:0"]
+    assert s.round_busy("ingest") == pytest.approx((3 + 8) / 2 / chips)
+    assert s.idle_share("ingest") == pytest.approx(1 - 11 / (22 * chips))
+    assert s.busy_seconds() == pytest.approx(11.0 / chips)
+    if chips == 4:
+        assert s.idle_share("ingest") >= 0.75
+    # TPU:0's gaps as in test_breakdown_...; an idle chip's whole window
+    # splits into submit 0–4 and 10–13, tick 4–9 and 13–19, drain 20–22,
+    # and 9–10 and 19–20 outside any span
+    n = chips - 1
+    idle = dict(s.breakdown()["idle_gaps"])
+    assert idle == {
+        "host in bench.submit": pytest.approx(6 + 7 * n),
+        "host in bench.tick": pytest.approx(3 + 11 * n),
+        "host in bench.drain": pytest.approx(1 + 2 * n),
+        "outside any span": pytest.approx(1 + 2 * n),
+    }
+    assert sum(idle.values()) == pytest.approx(11 + 22 * n)   # idle chip-seconds
+
+
+def test_chips_outside_the_cell_are_ignored():
+    """A one-chip cell on a host of four reads only TPU:0, whatever the
+    other chips run."""
+    s = tracing.reduce(_four_chip_trace(working=(0, 2, 3)), chips=1)
+    old = tracing.reduce(_trace(), chips=1)
+    assert list(s.busy_by_device) == ["/device:TPU:0"]
+    assert s.device_ops == old.device_ops
+    assert s.round_busy("ingest") == old.round_busy("ingest")
+    assert s.breakdown() == old.breakdown()
+
+
+def _roofline(summary):
+    run = types.SimpleNamespace(summary=summary, config=_config(), chips=summary.chips,
+                                peak=device.peaks("TPU v5 lite"))
+    return spec.reader("metrics", "chunk_update_roofline").read(run)
+
+
+def test_roofline_share_is_taken_at_the_cells_combined_peak():
+    """Four chips that each work as one chip did read a quarter of its
+    share; one chip of four doing all the work reads what one chip did."""
+    every = _four_chip_trace(working=range(4))
+    one = _roofline(tracing.reduce(every, chips=1))
+    assert _roofline(tracing.reduce(every, chips=4)) == pytest.approx(one / 4)
+    only_first = _four_chip_trace(working=(0,))
+    assert _roofline(tracing.reduce(only_first, chips=4)) == pytest.approx(one)
+
+
+@pytest.mark.parametrize("split", [(1, 0, 0, 0), (0.25, 0.25, 0.25, 0.25),
+                                   (0.7, 0.1, 0.1, 0.1), (0.4, 0.4, 0.2, 0)])
+@pytest.mark.parametrize("excess", [1.0, 1.5])
+def test_roofline_share_cannot_pass_100(split, excess):
+    """One ingest round whose busy chip-seconds, split across four chips in
+    any way, are ``excess`` times one chip's least time: the share reads
+    100 / ``excess``, never above 100."""
+    config = _config()
+    least, _ = work.roofline_seconds(
+        work.chunk_update(config, config["hosts"], config["chunk"]),
+        device.peaks("TPU v5 lite"))
+    host = _Plane("/host:CPU", [_Line("python", [
+        _Event("bench.round", 0, 1, kind="ingest"),
+        _Event("bench.drain", 1, 1.1),
+    ])])
+    chips = [_Plane(f"/device:TPU:{i}", [_Line("XLA Ops", [
+        _Event("fusion", 0, share * excess * least)] if share else [])])
+        for i, share in enumerate(split)]
+    reading = _roofline(tracing.reduce(_Profile([host] + chips), chips=4))
+    assert reading == pytest.approx(100 / excess)
+    assert reading <= 100 + 1e-9
+
+
 def test_reduce_reads_a_real_cpu_trace(tmp_path):
     """The capture path end to end on the CPU: host spans come back from the
     profiler; a CPU has no TPU plane, so no device time is read."""
@@ -106,7 +194,7 @@ def test_reduce_reads_a_real_cpu_trace(tmp_path):
 
     f = jax.jit(lambda x: x * 2)
     f(jnp.ones(8)).block_until_ready()
-    with tracing.capture(True) as cap:
+    with tracing.capture(True, chips=1) as cap:
         for _ in range(2):
             with jax.profiler.TraceAnnotation("bench.round", kind="query"):
                 with jax.profiler.TraceAnnotation("bench.tick"):
