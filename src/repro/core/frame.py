@@ -54,7 +54,7 @@ from typing import Any, Callable, Iterable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .backend import BackendSpec, get_backend
 from .plan import (
@@ -710,6 +710,11 @@ class FrameSession(_DeferredRequests):
         `repro.core.integrity`).  Snapshots from a compensated session only
         restore into a compensated session (the extra companion leaves are
         part of the state's structure).
+      devices: chips the tenant axis is split over, in contiguous blocks
+        (growing mode only; see `RollingStatsService`).  Not to be confused
+        with ``num_shards``, which counts ingest lanes.  Batched answers
+        stay split on the tenant axis, each chip's tenants finalized on
+        that chip.
     """
 
     def __init__(
@@ -722,10 +727,17 @@ class FrameSession(_DeferredRequests):
         num_buckets: Optional[int] = None,
         backend: BackendSpec = None,
         compensated: bool = False,
+        devices: int = 1,
     ):
+        if window is not None and devices > 1:
+            raise ValueError(
+                f"eviction mode (window={window}) keeps its tenants on one "
+                f"chip; devices={devices} is not supported with it"
+            )
         self.d = d
         self.num_users = num_users
         self.num_shards = num_shards
+        self.devices = devices
         self.window = window
         self._num_buckets = num_buckets
         self._backend = backend
@@ -772,7 +784,7 @@ class FrameSession(_DeferredRequests):
         self._plan = StatPlan(list(self._recorded), d=self.d,
                               backend=self._backend,
                               compensated=self.compensated)
-        from ..serving.rolling import RollingStatsService
+        from ..serving.rolling import TENANTS, RollingStatsService
 
         self._services = [
             RollingStatsService(
@@ -781,17 +793,26 @@ class FrameSession(_DeferredRequests):
                 num_shards=self.num_shards,
                 window=self.window,
                 num_buckets=self._num_buckets,
+                devices=self.devices,
             )
             for g in self._plan.groups
         ]
         # jit caches one trace per requested batch size, so a steady read
         # load (the gateway's per-tick coalesced query) re-traces nothing:
         # the whole multi-user read is the services' gather/⊕-fold programs
-        # plus this ONE vmapped fused-finalize program.
-        def finalize_batch(states):
-            return self._plan.finalize(tuple(states), cache=False)
+        # plus this ONE vmapped fused-finalize program, run by each chip
+        # over its own tenants.
+        finalize = jax.vmap(
+            lambda states: self._plan.finalize(tuple(states), cache=False))
 
-        self._finalize_batch = jax.jit(jax.vmap(finalize_batch))
+        def finalize_batch(states):
+            return jax.shard_map(finalize, mesh=self._services[0].mesh,
+                                 in_specs=P(TENANTS), out_specs=P(TENANTS),
+                                 check_vma=False)(states)
+
+        self._finalize_batch = jax.jit(
+            finalize_batch,
+            out_shardings=NamedSharding(self._services[0].mesh, P(TENANTS)))
 
     # -- write path ----------------------------------------------------------
     def ingest(
@@ -818,13 +839,25 @@ class FrameSession(_DeferredRequests):
         states = tuple(svc.partial(user_id) for svc in self._services)
         return self._plan.finalize(states, cache=False)
 
+    def lay_out(self, user_ids) -> tuple:
+        """``(laid, rows)``: the batch's ids arranged by owner chip, and
+        each id's row there (None where they already are); see
+        `RollingStatsService.lay_out`."""
+        self._ensure_plan()
+        return self._services[0].lay_out(user_ids)
+
     def query_batch(self, user_ids) -> dict:
         """Vmapped multi-user read: one gather + one compiled ⊕-fold per
         plan group, then ONE jit-cached vmapped fused finalize — results
-        have a leading ``len(user_ids)`` axis."""
+        have a leading ``len(user_ids)`` axis.  Ids laid out by chip
+        (:meth:`lay_out`) get answers split on the tenant axis as the
+        chips computed them; other ids are laid out, and the answers
+        gathered back into the given order."""
         self._ensure_plan()
-        merged = tuple(svc.partials_batch(user_ids) for svc in self._services)
-        return self._finalize_batch(merged)
+        laid, rows = self.lay_out(user_ids)
+        merged = tuple(svc.partials_batch(laid) for svc in self._services)
+        out = self._finalize_batch(merged)
+        return out if rows is None else jax.tree.map(lambda l: l[rows], out)
 
     # -- durability ----------------------------------------------------------
     def export_state(self) -> dict:

@@ -227,29 +227,49 @@ def answer_pieces(leaves: list, bounds: tuple) -> list:
             for lo, hi in zip(bounds, bounds[1:])]
 
 
-def _fetch(results) -> tuple:
-    """Copy one query batch's answers (leading tenant axis) to the host.
+def _chip_rows(leaves: list) -> list:
+    """Each chip's rows of every leaf (split on the leading tenant axis),
+    in row order: no copy, no device work."""
+    if len(leaves[0].sharding.device_set) == 1:
+        return [leaves]
 
-    Returns ``(path, size, pieces)``: ``pieces[j]`` is the tree of answers
-    of tenants ``j * size`` to ``(j + 1) * size``.  A ``jax.device_get`` of
-    the batch puts each leaf in a fresh host allocation, and a leaf of
+    def rows(leaf):
+        return [sh.data for sh in sorted(leaf.addressable_shards,
+                                         key=lambda sh: sh.index[0].start)]
+
+    return [list(chip) for chip in zip(*map(rows, leaves))]
+
+
+def _fetch(results) -> tuple:
+    """Copy one query batch's answers (leading tenant axis, split evenly
+    over the chips that computed them) to the host.
+
+    Returns ``(path, size, pieces)``: each chip's rows come in pieces of
+    ``size`` (its last may be shorter), chip after chip, so that
+    ``pieces[c * k + j]`` (k pieces a chip) is the tree of answers of the
+    chip's rows ``j * size`` to ``(j + 1) * size``.  A ``jax.device_get``
+    of the batch puts each leaf in a fresh host allocation, and a leaf of
     ``_PIECE_BYTES`` or more is mapped anew from the kernel every tick:
     the copy then pays a first-touch fault on every page, which on a TPU
     host costs many times the copy itself.  So where a leaf is that large
     and the device does not hold it in the host's layout (where it does,
-    the CPU, ``device_get`` copies nothing), the device cuts the batch into
+    the CPU, ``device_get`` copies nothing), each chip cuts its rows into
     pieces below ``_PIECE_BYTES``, which the host's heap serves from pages
-    it keeps.
+    it keeps.  One ``device_get`` fetches from every chip.
     """
     leaves, treedef = jax.tree.flatten(results)
-    n = leaves[0].shape[0]
+    chips = _chip_rows(leaves)
+    n = chips[0][0].shape[0]
     # a sixteenth of room below the threshold for the allocator's rounding
-    size = max(1, _PIECE_BYTES * 15 // 16 // max(leaf.nbytes // n for leaf in leaves))
+    row_bytes = max(leaf.nbytes // leaf.shape[0] for leaf in leaves)
+    size = max(1, _PIECE_BYTES * 15 // 16 // row_bytes)
     if size >= n or all(map(_row_major, leaves)) or not _keep_freed_pages():
-        return "device_get", n, [jax.device_get(results)]
+        host = jax.device_get(chips)
+        return "device_get", n, [treedef.unflatten(chip) for chip in host]
     bounds = tuple(range(0, n, size)) + (n,)
-    host = jax.device_get(answer_pieces(leaves, bounds))
-    return "pieces", size, [treedef.unflatten(piece) for piece in host]
+    host = jax.device_get([answer_pieces(chip, bounds) for chip in chips])
+    return "pieces", size, [treedef.unflatten(piece)
+                            for chip in host for piece in chip]
 
 
 def _event_loop() -> asyncio.AbstractEventLoop:
@@ -672,19 +692,33 @@ class StatsGateway:
                 seen.add(req.tenant)
                 groups.setdefault(req.chunk.shape[0], []).append(req)
             self._ingest_q.extend(carry)
-            batches = [
-                (reqs, np.asarray([r.tenant for r in reqs], np.int32),
-                 np.stack([r.chunk for r in reqs]))
-                for length, reqs in sorted(groups.items()) if length
-            ]
+            batches = []
+            for length, reqs in sorted(groups.items()):
+                if not length:
+                    continue
+                # rows in owner-chip order, each chip's padded to the
+                # longest with a repeat of a chunk of the batch (its id
+                # -1: the scatter drops it), stacked once
+                ids, at = self.session.lay_out(
+                    np.asarray([r.tenant for r in reqs], np.int32))
+                chunks = [r.chunk for r in reqs]
+                if at is not None:
+                    laid = [chunks[0]] * len(ids)
+                    for i, row in enumerate(at):
+                        laid[row] = chunks[i]
+                    chunks = laid
+                batches.append((reqs, ids, at, np.stack(chunks)))
+            pad = sum(len(b[1]) - len(b[0]) for b in batches)
+            self.counters["ingest_pad_rows"] += pad
             # length: the longest chunk (the only one when the tick runs
             # one scatter program)
-            span.set_metadata(rows=sum(len(b[0]) for b in batches),
+            span.set_metadata(rows=sum(len(b[1]) for b in batches),
                               length=max(groups, default=0),
-                              bytes=sum(b[2].nbytes for b in batches))
+                              bytes=sum(b[3].nbytes for b in batches),
+                              chips=self.session.devices, pad_rows=pad)
         absorbed = list(groups.get(0, ()))  # empty chunks: no-ops, resolved too
         done = 0
-        for reqs, ids, batch in batches:
+        for reqs, ids, at, batch in batches:
             if self.config.sentinel:
                 # ONE fused jitted program: per-chunk verdict + sanitized
                 # copy together; the verdict is the only host sync, and the
@@ -692,15 +726,18 @@ class StatsGateway:
                 # stays on device for the scatter below.
                 verdict, clean = sentinel_scan(batch)
                 self.counters["sentinel_scans"] += 1
+                if at is not None:
+                    verdict = verdict[at]
                 if not verdict.all():
                     keep = self._apply_sentinel(reqs, verdict)
                     if not keep:
                         continue
                     if len(keep) < len(reqs):
-                        sel = np.asarray(keep)
+                        # a dropped chunk's row becomes a padding row
+                        drop = np.setdiff1d(np.arange(len(reqs)), keep)
+                        ids = ids.copy()
+                        ids[drop if at is None else at[drop]] = -1
                         reqs = [reqs[i] for i in keep]
-                        ids = ids[sel]
-                        clean = clean[sel]  # device gather — no host sync
                 batch = clean
             try:
                 self.session.ingest(ids, batch)
@@ -775,7 +812,9 @@ class StatsGateway:
         order: Dict[int, int] = {}
         for req in pending:
             order.setdefault(req.tenant, len(order))
-        ids = np.fromiter(order.keys(), np.int32, len(order))
+        ids, at = self.session.lay_out(
+            np.fromiter(order.keys(), np.int32, len(order)))
+        self.counters["query_pad_rows"] += len(ids) - len(order)
         try:
             with TraceAnnotation("repro.query.dispatch", tenants=len(order)):
                 results = self.session.query_batch(ids)
@@ -790,16 +829,21 @@ class StatsGateway:
         # the whole batch leaves the device in one fetch; per-waiter slicing
         # is then numpy views, not thousands of tiny device index dispatches
         nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(results))
-        with TraceAnnotation("repro.query.fetch", bytes=nbytes) as span:
+        chips = self.session.devices
+        with TraceAnnotation("repro.query.fetch", bytes=nbytes,
+                             chips=chips) as span:
             faults = _minor_faults()
             path, size, pieces = _fetch(results)
             span.set_metadata(minflt=_minor_faults() - faults, path=path,
                               pieces=len(pieces))
+        # each chip's rows come in len(pieces) // chips pieces of size
+        rows, per_chip = len(ids) // chips, len(pieces) // chips
         with TraceAnnotation("repro.query.resolve", n=len(pending)):
             for req in pending:
                 pos = order[req.tenant]
-                host = pieces[pos // size]
-                value = jax.tree.map(lambda l: l[pos % size], host)
+                chip, row = divmod(pos if at is None else int(at[pos]), rows)
+                host = pieces[chip * per_chip + row // size]
+                value = jax.tree.map(lambda l: l[row % size], host)
                 if req.only is not None:
                     value = {k: value[k] for k in req.only}
                 self._resolve(req, value, self._lat_query)

@@ -66,6 +66,19 @@ consequences, and the machinery that answers them (`repro.core.integrity`):
     error companion per stat leaf so readout recovers what rounding
     discarded (pinned by benchmarks/bench_integrity.py).
 
+**Tenants across chips** (``devices=``): the stacked buffers' tenant
+axis is split over a one-axis mesh of ``devices`` chips in contiguous
+blocks, tenant ``t`` on chip ``t // block`` at local index ``t % block``
+(the axis is padded up to ``devices × block``; padding tenants are never
+addressed).  Every program is one SPMD program over the mesh
+(`jax.shard_map`) in which each chip gathers, updates, scatters and folds
+only its own tenants, from its own rows of the batch: no state moves
+between chips and no collective runs.  A batch reaches the programs laid
+out by chip (:meth:`RollingStatsService.lay_out`): ``devices`` blocks of
+equal length, block j holding chip j's tenants and padding rows (id
+``-1``), which change and answer nothing.  One chip is the same code on a
+one-chip mesh, where every batch is already laid out.
+
 The compute substrate of the ingest hot loop is the engine's backend
 (`repro.core.backend`): build the engine with
 ``lag_sum_engine(..., backend="pallas")`` and every batched ``ingest``
@@ -80,11 +93,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.integrity import lane_health
 from ..core.streaming import PartialState, StreamingEngine
 
 __all__ = ["RollingStatsService"]
+
+TENANTS = "tenants"      # the mesh axis the tenant axis is split over
+# stacked lane leaves carry tenants on axis 1; per-row batch arrays on axis 0
+_LANES, _ROWS = P(None, TENANTS), P(TENANTS)
 
 
 def _coerce_import_leaf(key: str, want: np.dtype, new):
@@ -96,14 +114,15 @@ def _coerce_import_leaf(key: str, want: np.dtype, new):
     the snapshot was produced by a different engine config, and silently
     casting it would both corrupt values and compile duplicate scatter
     programs keyed on the stray dtype (the PR 6 ``t0`` int32 bug class).
+    The leaf stays where it is (host or device); the caller places it.
     """
     arr = new if hasattr(new, "dtype") else np.asarray(new)
     have = np.dtype(arr.dtype)
     want = np.dtype(want)
     if have == want:
-        return jnp.asarray(arr)
+        return arr
     if have.kind == want.kind:
-        return jnp.asarray(arr, want)
+        return arr.astype(want)
     raise ValueError(
         f"snapshot leaf {key!r} has dtype {have} but this service holds "
         f"{want} — a {have.kind!r}→{want.kind!r} kind change cannot come "
@@ -127,6 +146,9 @@ class RollingStatsService:
         grid (chunk length ≤ bucket span, never straddling a boundary).
       num_buckets: ring size in eviction mode (default 8); ``window`` must
         divide evenly into it.
+      devices: chips the tenant axis is split over (the first ``devices``
+        of ``jax.devices()``), in contiguous blocks of tenants (see the
+        module docstring).  Growing mode only.
     """
 
     def __init__(
@@ -136,13 +158,32 @@ class RollingStatsService:
         num_shards: int = 1,
         window: Optional[int] = None,
         num_buckets: Optional[int] = None,
+        devices: int = 1,
     ):
         if num_users <= 0 or num_shards <= 0:
             raise ValueError("num_users and num_shards must be positive")
+        if devices <= 0:
+            raise ValueError(f"devices must be positive, got {devices}")
+        if window is not None and devices > 1:
+            raise ValueError(
+                f"eviction mode (window={window}) keeps its tenants on one "
+                f"chip; devices={devices} is not supported with it"
+            )
+        chips = jax.devices()
+        if devices > len(chips):
+            raise ValueError(
+                f"devices={devices} but JAX sees {len(chips)} device(s)"
+            )
         self.engine = engine
         self.num_users = num_users
         self.num_shards = num_shards
         self.window = window
+        self.devices = devices
+        # tenants per chip; the tenant axis holds devices × block slots
+        self.block = -(-num_users // devices)
+        self.mesh = Mesh(np.asarray(chips[:devices]), (TENANTS,))
+        self._lane_sharding = NamedSharding(self.mesh, _LANES)
+        self._row_sharding = NamedSharding(self.mesh, _ROWS)
         if window is None:
             if num_buckets is not None:
                 raise ValueError("num_buckets only applies with window= set")
@@ -166,13 +207,18 @@ class RollingStatsService:
             self.bucket_len = window // self.num_buckets
             num_lanes = self.num_buckets
         self._num_lanes = num_lanes
-        # One stacked pytree, leading axes (num_lanes, num_users): every
+        # One stacked pytree, leading axes (num_lanes, tenant slots): every
         # lane lives in the same buffers and every ingest/query below is a
-        # single jit program regardless of which lane it addresses.
-        one = engine.init_batch(num_users)
-        self._lanes = jax.tree.map(
-            lambda l: jnp.broadcast_to(l, (num_lanes,) + l.shape), one
-        )
+        # single jit program regardless of which lane it addresses.  Made
+        # in place on the mesh: no chip ever holds another's block.
+        slots = self.block * devices
+        self._lanes = jax.jit(
+            lambda: jax.tree.map(
+                lambda l: jnp.broadcast_to(l, (num_lanes,) + l.shape),
+                engine.init_batch(slots),
+            ),
+            out_shardings=self._lane_sharding,
+        )()
         # Total samples ever ingested per user — the eviction ring's global
         # cursor.  Kept as a HOST array: the cursor is only ever read for
         # alignment checks and bucket derivation, and a device-resident
@@ -185,19 +231,34 @@ class RollingStatsService:
         self._lane_health = np.ones((num_lanes, num_users), bool)
         self._audit_sweep = jax.jit(lane_health)
 
-        def scatter_update(lanes, shard, user_ids, chunks, t0):
+        def per_chip(body, *specs, out=_LANES):
+            # body over one chip's block of the lanes and its own rows of
+            # the batch; ids are local (block = a padding row: the gather
+            # clamps it, the scatter drops it)
+            return jax.shard_map(body, mesh=self.mesh, in_specs=(_LANES,) + specs,
+                                 out_specs=out, check_vma=False)
+
+        def scatter_rows(lanes, shard, user_ids, chunks, t0):
             sub = jax.tree.map(lambda l: l[shard, user_ids], lanes)
             new = jax.vmap(engine.update)(sub, chunks, t0)
             return jax.tree.map(
                 lambda l, nl: l.at[shard, user_ids].set(nl), lanes, new
             )
 
+        def scatter_update(lanes, shard, user_ids, chunks, t0):
+            return per_chip(scatter_rows, P(), _ROWS, _ROWS, _ROWS)(
+                lanes, shard, user_ids, chunks, t0)
+
         # jit caches one program per (arrival batch, chunk length) shape —
         # shared by ALL lanes (shard is a traced scalar) — and donates the
         # lane buffers: steady-state ingest updates them in place.
-        self._scatter_update = jax.jit(scatter_update, donate_argnums=0)
+        # The output's sharding is pinned: on a one-chip mesh XLA would
+        # hand some leaves back unsplit, and the next call would compile
+        # again for them.
+        self._scatter_update = jax.jit(scatter_update, donate_argnums=0,
+                                       out_shardings=self._lane_sharding)
 
-        def scatter_evict(lanes, user_ids, chunks, counts):
+        def evict_rows(lanes, user_ids, chunks, counts):
             # Ring ingest: the chunk's bucket is derived from the user's
             # global cursor; a cursor on a bucket boundary means the slot
             # holds the span from num_buckets rings ago — reset it to the
@@ -217,7 +278,12 @@ class RollingStatsService:
                 lambda l, nl: l.at[bucket, user_ids].set(nl), lanes, new
             )
 
-        self._scatter_evict = jax.jit(scatter_evict, donate_argnums=0)
+        def scatter_evict(lanes, user_ids, chunks, counts):
+            return per_chip(evict_rows, _ROWS, _ROWS, _ROWS)(
+                lanes, user_ids, chunks, counts)
+
+        self._scatter_evict = jax.jit(scatter_evict, donate_argnums=0,
+                                      out_shardings=self._lane_sharding)
 
         def lane_fold(stacked):
             # ⊕-fold the leading lane axis of a stacked (S, k, …) pytree
@@ -251,10 +317,15 @@ class RollingStatsService:
                 )
             return acc
 
-        def gather_merge(lanes, user_ids):
+        def gather_rows(lanes, user_ids):
             return lane_fold(jax.tree.map(lambda l: l[:, user_ids], lanes))
 
-        self._gather_merge = jax.jit(gather_merge)
+        def gather_merge(lanes, user_ids):
+            # each chip's merged states stay on it, split on the tenant axis
+            return per_chip(gather_rows, _ROWS, out=_ROWS)(lanes, user_ids)
+
+        self._gather_merge = jax.jit(gather_merge,
+                                     out_shardings=self._row_sharding)
 
     @property
     def backend(self):
@@ -267,7 +338,10 @@ class RollingStatsService:
         plus the eviction cursor.  Leaves are HOST copies (``device_get``),
         so the snapshot survives the next ingest donating the live lane
         buffers — safe to hand to an async checkpoint writer
-        (`repro.checkpoint.manager.CheckpointManager.save`)."""
+        (`repro.checkpoint.manager.CheckpointManager.save`).  The lanes'
+        tenant axis holds every slot, padding included (tenant ``t`` at
+        index ``t``), so a snapshot restores into a service of the same
+        ``devices``."""
         return {
             "lanes": jax.device_get(self._lanes),
             "counts": np.array(self._counts),
@@ -296,13 +370,14 @@ class RollingStatsService:
             raise ValueError(
                 f"snapshot lane shapes {[m[1] for m in mismatched]} do not "
                 f"match this service's {[m[0] for m in mismatched]} — "
-                "num_users / num_shards / window must equal the exporter's"
+                "num_users / num_shards / window / devices must equal the "
+                "exporter's"
             )
         cur_flat, treedef = jax.tree_util.tree_flatten_with_path(self._lanes)
         new_leaves = [
-            _coerce_import_leaf(
+            jax.device_put(_coerce_import_leaf(
                 "lanes" + jax.tree_util.keystr(path), cur.dtype, new
-            )
+            ), self._lane_sharding)
             for (path, cur), new in zip(cur_flat, jax.tree.leaves(lanes))
         ]
         self._lanes = jax.tree.unflatten(treedef, new_leaves)
@@ -336,7 +411,8 @@ class RollingStatsService:
         lane of the user is healthy."""
         # np.array (not asarray): own the buffer — device_get views are
         # read-only and import_tenant writes the mask in place.
-        mask = np.array(self._audit_sweep(self._lanes))
+        sweep = np.asarray(self._audit_sweep(self._lanes))
+        mask = np.array(sweep[:, : self.num_users])
         self._lane_health = mask
         return mask.all(axis=0)
 
@@ -398,7 +474,8 @@ class RollingStatsService:
                     f"{tuple(np.shape(new))}, expected {expect}"
                 )
             coerced = _coerce_import_leaf(key, cur.dtype, new)
-            out.append(cur.at[:, u].set(coerced))
+            # kept on the lanes' sharding, so the cached programs still fit
+            out.append(jax.device_put(cur.at[:, u].set(coerced), cur.sharding))
         self._lanes = jax.tree.unflatten(treedef, out)
         count = np.asarray(state["counts"])
         if count.dtype.kind not in "iu" or count.shape != ():
@@ -415,6 +492,49 @@ class RollingStatsService:
             raise ValueError(f"user_id {u} out of range [0, {self.num_users})")
         return u
 
+    # -- layout ------------------------------------------------------------
+    def lay_out(self, user_ids) -> tuple:
+        """Arrange a batch's tenant ids by the chip that owns them.
+
+        Returns ``(laid, rows)``.  ``laid`` holds ``devices`` blocks of
+        equal length; block j holds chip j's tenants of the batch, in the
+        batch's order, and ``-1`` (a padding row) up to the longest block.
+        ``rows[i]`` is the position of ``user_ids[i]`` in ``laid``.  Where
+        the ids already are laid out so (always on one chip), ``laid`` is
+        the ids and ``rows`` is None.  ``-1`` ids are accepted only there.
+        Host-side; no device work.
+        """
+        ids = np.asarray(user_ids)
+        n, chips = ids.shape[0], self.devices
+        if chips == 1:
+            return ids, None
+        if n % chips == 0:
+            blocks = ids.reshape(chips, n // chips)
+            owner = np.arange(chips)[:, None]
+            if np.all((blocks < 0) | (blocks // self.block == owner)):
+                return ids, None
+        if n and ids.min() < 0:
+            raise ValueError(
+                "padding ids (-1) are only accepted in a batch laid out by "
+                "chip (see lay_out)"
+            )
+        owner = ids // self.block
+        counts = np.bincount(owner, minlength=chips)
+        width = int(counts.max(initial=0))
+        order = np.argsort(owner, kind="stable")
+        rank = np.empty(n, np.int64)
+        rank[order] = np.arange(n) - (np.cumsum(counts) - counts)[owner[order]]
+        rows = owner * width + rank
+        laid = np.full(chips * width, -1, np.int32)
+        laid[rows] = ids
+        return laid, rows
+
+    def _local(self, laid: np.ndarray) -> jax.Array:
+        """Laid-out ids → each chip's local ids (``block`` for padding),
+        placed on the chips that own them."""
+        local = np.where(laid < 0, self.block, laid % self.block)
+        return jax.device_put(local.astype(np.int32), self._row_sharding)
+
     # -- write path --------------------------------------------------------
     def ingest(
         self,
@@ -427,7 +547,10 @@ class RollingStatsService:
         ``user_ids[i]``'s series on lane ``shard``.
 
         Args:
-          user_ids: (k,) int — distinct users in this batch.
+          user_ids: (k,) int — distinct users in this batch; ``-1`` marks
+            a padding row where the batch is laid out by chip
+            (:meth:`lay_out`).  A batch that is not is laid out here, at
+            the cost of one copy of the chunks.
           chunks: (k, c, d) — equal-length chunk per user (pad+resend
             shorter arrivals separately; chunk granularity is free in
             growing mode; in eviction mode chunks must tile the bucket
@@ -445,23 +568,37 @@ class RollingStatsService:
             # match the old jnp.asarray(user_ids, jnp.int32) coercion —
             # float-typed ids ingested fine before the host-side validation
             ids = ids.astype(np.int64)
+        real = ids[ids >= 0]
         # .at[ids].set would silently keep only one of two conflicting
         # scattered states, and jit scatter silently DROPS out-of-bounds
         # ids (the gather on read would clamp to another user) — reject the
         # caller slips instead of losing or cross-wiring data.
-        if np.unique(ids).shape[0] != ids.shape[0]:
+        if np.unique(real).shape[0] != real.shape[0]:
             raise ValueError("user_ids must be distinct within one ingest batch")
-        if ids.shape[0] and not (0 <= ids.min() and ids.max() < self.num_users):
-            raise ValueError(f"user_ids must lie in [0, {self.num_users})")
+        if ids.shape[0] and not (-1 <= ids.min() and ids.max() < self.num_users):
+            raise ValueError(
+                f"user_ids must lie in [0, {self.num_users}) (or be -1, "
+                "a padding row)"
+            )
         # num_shards is the caller-facing lane count in BOTH modes: the
         # eviction ring pins it to 1, and its internal bucket lanes are not
         # addressable (the old check tested _num_lanes — the ring size — so
         # the message promised a range the check didn't enforce).
         if not 0 <= shard < self.num_shards:
             raise ValueError(f"shard {shard} out of range [0, {self.num_shards})")
-        with TraceAnnotation("repro.ingest.h2d") as span:
-            user_ids = jnp.asarray(ids, jnp.int32)
-            chunks = jnp.asarray(chunks)
+        laid, rows = self.lay_out(ids)
+        if rows is not None:
+            src = np.zeros(laid.shape[0], np.int64)
+            src[rows] = np.arange(ids.shape[0])
+            chunks = chunks[src] if isinstance(chunks, jax.Array) \
+                else np.asarray(chunks)[src]
+            if t0 is not None:
+                t0 = np.asarray(t0)[src]
+        elif not isinstance(chunks, jax.Array):
+            chunks = np.asarray(chunks)
+        with TraceAnnotation("repro.ingest.h2d", chips=self.devices) as span:
+            user_ids = self._local(laid)
+            chunks = jax.device_put(chunks, self._row_sharding)
             span.set_metadata(bytes=chunks.nbytes)
         if chunks.shape[1] == 0:
             # nothing to absorb — and in eviction mode the boundary reset
@@ -479,7 +616,8 @@ class RollingStatsService:
                     f"chunk length {c} exceeds the eviction bucket span "
                     f"{self.bucket_len} (= window / num_buckets)"
                 )
-            starts = self._counts[ids]  # host cursor: no device sync
+            # host cursor: no device sync; a padding row's is 0
+            starts = np.where(ids >= 0, self._counts[np.maximum(ids, 0)], 0)
             if np.any(
                 starts // self.bucket_len != (starts + c - 1) // self.bucket_len
             ):
@@ -487,29 +625,26 @@ class RollingStatsService:
                     "chunk would straddle an eviction bucket boundary; "
                     f"chunks must tile the {self.bucket_len}-sample bucket grid"
                 )
-            with TraceAnnotation("repro.ingest.dispatch", rows=len(ids)):
+            with TraceAnnotation("repro.ingest.dispatch", rows=len(laid)):
                 self._lanes = self._scatter_evict(
                     self._lanes, user_ids, chunks,
-                    jnp.asarray(starts, jnp.int32),
+                    jax.device_put(starts.astype(np.int32), self._row_sharding),
                 )
+            self._counts[real] += c
         else:
-            if t0 is None:
-                # update() falls back to each state's own cursor.
-                t0 = jnp.zeros(user_ids.shape, jnp.int32)
-            with TraceAnnotation("repro.ingest.dispatch", rows=len(ids)):
+            # update() falls back to each state's own cursor where t0 is 0;
+            # pin the dtype: a caller-dependent one compiled (and cached)
+            # duplicate donated scatter programs for the same shapes
+            t0 = np.zeros(laid.shape, np.int32) if t0 is None \
+                else np.asarray(t0, np.int32)
+            with TraceAnnotation("repro.ingest.dispatch", rows=len(laid)):
                 self._lanes = self._scatter_update(
                     self._lanes,
                     jnp.asarray(shard, jnp.int32),
                     user_ids,
                     chunks,
-                    # pin the dtype: a bare asarray leaves it
-                    # caller-dependent, so mixed int32/int64 t0 arrivals
-                    # compiled (and cached) duplicate donated scatter
-                    # programs for the same shapes
-                    jnp.asarray(t0, jnp.int32),
+                    jax.device_put(t0, self._row_sharding),
                 )
-        if self.window is not None:
-            self._counts[ids] += chunks.shape[1]
 
     # -- read path ---------------------------------------------------------
     def partial(self, user_id: int) -> PartialState:
@@ -522,10 +657,13 @@ class RollingStatsService:
         (leading ``len(user_ids)`` axis): one gather pulls every requested
         user's lane states, one compiled reduce ⊕-folds the lane axis.
         The batched read path multi-statistic front-ends
-        (`repro.core.frame.FrameSession`) build on."""
-        return self._gather_merge(
-            self._lanes, jnp.asarray(user_ids, jnp.int32)
-        )
+        (`repro.core.frame.FrameSession`) build on.  Ids laid out by chip
+        (:meth:`lay_out`, ``-1`` rows answer nothing meaningful) stay split
+        on the tenant axis, each chip's on that chip; other ids are laid
+        out and their rows gathered back into the given order."""
+        laid, rows = self.lay_out(user_ids)
+        merged = self._gather_merge(self._lanes, self._local(laid))
+        return merged if rows is None else jax.tree.map(lambda l: l[rows], merged)
 
     def query(self, user_id: int, finalizer: Callable, *args, **kwargs) -> Any:
         """Rolling estimate for one user: merge lanes, then finalize with an
@@ -548,7 +686,9 @@ class RollingStatsService:
     def lengths(self) -> jax.Array:
         """(num_users,) samples ingested per user (total, incl. evicted)."""
         if self.window is None:
-            return jnp.sum(self._lanes.length, axis=0)
+            total = jnp.sum(self._lanes.length, axis=0)
+            return total if total.shape[0] == self.num_users \
+                else total[: self.num_users]
         return jnp.asarray(self._counts, jnp.int32)
 
     def retained_lengths(self) -> jax.Array:

@@ -493,8 +493,9 @@ def test_each_tick_opens_one_span_per_phase_inside_repro_tick(tmp_path):
     batch = N * c * D * 4
     ingest = {e[0]: e[3] for e in inside[0]}
     assert sorted(e[0] for e in inside[0]) == sorted(INGEST_SPANS)
-    assert ingest["repro.ingest.stack"] == {"rows": N, "length": c, "bytes": batch}
-    assert ingest["repro.ingest.h2d"] == {"bytes": batch}
+    assert ingest["repro.ingest.stack"] == {"rows": N, "length": c, "bytes": batch,
+                                            "chips": 1, "pad_rows": 0}
+    assert ingest["repro.ingest.h2d"] == {"bytes": batch, "chips": 1}
     assert ingest["repro.ingest.dispatch"] == {"rows": N}
     assert ingest["repro.ingest.resolve"] == {"n": N}
 
@@ -504,7 +505,8 @@ def test_each_tick_opens_one_span_per_phase_inside_repro_tick(tmp_path):
     one = sum(np.asarray(leaf).nbytes for leaf in jax.tree.leaves(answers[0]))
     fetch = dict(query["repro.query.fetch"])
     assert fetch.pop("minflt") >= 0
-    assert fetch == {"bytes": N * one, "path": "device_get", "pieces": 1}
+    assert fetch == {"bytes": N * one, "path": "device_get", "pieces": 1,
+                     "chips": 1}
     assert query["repro.query.resolve"] == {"n": N}
 
 
