@@ -821,15 +821,15 @@ class FrameSession(_DeferredRequests):
         chunks: jax.Array,
         shard: int = 0,
         t0: Optional[jax.Array] = None,
-    ) -> None:
+    ) -> list:
         """Absorb one arrival batch: ``chunks[i]`` extends user
         ``user_ids[i]``'s series (see `RollingStatsService.ingest`).
         Built-in requests compile to a single plan group, so this is ONE
         donated scatter-update program however many statistics the session
-        tracks."""
+        tracks.  Returns the batch as each plan group placed it."""
         self._ensure_plan()
-        for svc in self._services:
-            svc.ingest(user_ids, chunks, shard=shard, t0=t0)
+        return [svc.ingest(user_ids, chunks, shard=shard, t0=t0)
+                for svc in self._services]
 
     # -- read path -----------------------------------------------------------
     def query(self, user_id: int) -> dict:

@@ -31,6 +31,11 @@ What was missing is a concurrency front door.  This module is it:
     library's mmap threshold, and malloc is told to keep freed pages, so
     each tick's answers land in host memory that stays resident instead
     of in pages the kernel maps and faults in anew (``_fetch``);
+  * **the ingest batch's staging**: a coalesced batch of ``_PIECE_BYTES``
+    or more is stacked into a host buffer the gateway keeps across ticks
+    (one a batch shape), rewritten only once the chips hold their copy of
+    the last batch placed from it; smaller batches are a fresh
+    ``np.stack``, whose pages the heap recycles (``_stage``);
   * **tracing**: each tick is a ``repro.tick`` step span, with one
     ``repro.ingest.*`` / ``repro.query.*`` span per phase inside it, in
     the profiler's trace when one is being taken (never one per request);
@@ -188,7 +193,8 @@ class GatewayConfig:
 # mmap threshold it accepts), and the host receives a large query batch's
 # answers in pieces below it; the heap keeps up to _KEEP_BYTES of freed
 # memory (mallopt's largest value), so each tick's answers land in pages
-# that earlier ticks already faulted in
+# that earlier ticks already faulted in.  An ingest batch of this size or
+# more is stacked into a buffer the gateway keeps instead.
 _PIECE_BYTES = 32 << 20
 _KEEP_BYTES = (1 << 31) - 1
 _M_TRIM_THRESHOLD, _M_MMAP_THRESHOLD = -1, -3
@@ -272,6 +278,14 @@ def _fetch(results) -> tuple:
                             for chip in host for piece in chip]
 
 
+def _aliases(placed: jax.Array, buf: np.ndarray) -> bool:
+    """Whether a device array placed from ``buf`` is ``buf`` itself, as a
+    device that reads host memory in place (the CPU) may make it."""
+    lo = buf.ctypes.data
+    return any(lo <= shard.data.unsafe_buffer_pointer() < lo + buf.nbytes
+               for shard in placed.addressable_shards)
+
+
 def _event_loop() -> asyncio.AbstractEventLoop:
     try:
         return asyncio.get_running_loop()
@@ -286,6 +300,14 @@ class _Pending:
     t_submit: float
     chunk: Optional[np.ndarray] = None     # ingest only
     only: Optional[tuple] = None           # query only: request-name filter
+
+
+@dataclasses.dataclass
+class _Kept:
+    """A host buffer that ingest batches of one shape are stacked into."""
+
+    buf: np.ndarray
+    placed: list = dataclasses.field(default_factory=list)  # device copies
 
 
 class _TokenBuckets:
@@ -370,6 +392,8 @@ class StatsGateway:
                 self._class_of(t).query_per_tick),
         )
         self._ingest_q: Deque[_Pending] = collections.deque()
+        # (rows, length, d, dtype) -> the buffer such batches are stacked into
+        self._kept: Dict[tuple, _Kept] = {}
         self._query_q: Deque[_Pending] = collections.deque()
         self._tick_lock = asyncio.Lock()
         self._serve_task: Optional[asyncio.Task] = None
@@ -693,6 +717,7 @@ class StatsGateway:
                 groups.setdefault(req.chunk.shape[0], []).append(req)
             self._ingest_q.extend(carry)
             batches = []
+            staged = set()
             for length, reqs in sorted(groups.items()):
                 if not length:
                     continue
@@ -707,7 +732,12 @@ class StatsGateway:
                     for i, row in enumerate(at):
                         laid[row] = chunks[i]
                     chunks = laid
-                batches.append((reqs, ids, at, np.stack(chunks)))
+                batch, key, how = self._stage(chunks)
+                staged.add(how)
+                batches.append((reqs, ids, at, batch, key))
+            # a shape no batch of this tick used gives its buffer up
+            for key in self._kept.keys() - {b[4] for b in batches}:
+                del self._kept[key]
             pad = sum(len(b[1]) - len(b[0]) for b in batches)
             self.counters["ingest_pad_rows"] += pad
             # length: the longest chunk (the only one when the tick runs
@@ -715,10 +745,12 @@ class StatsGateway:
             span.set_metadata(rows=sum(len(b[1]) for b in batches),
                               length=max(groups, default=0),
                               bytes=sum(b[3].nbytes for b in batches),
-                              chips=self.session.devices, pad_rows=pad)
+                              chips=self.session.devices, pad_rows=pad,
+                              staged="new" if "new" in staged else
+                              "kept" if "kept" in staged else "heap")
         absorbed = list(groups.get(0, ()))  # empty chunks: no-ops, resolved too
         done = 0
-        for reqs, ids, at, batch in batches:
+        for reqs, ids, at, batch, key in batches:
             if self.config.sentinel:
                 # ONE fused jitted program: per-chunk verdict + sanitized
                 # copy together; the verdict is the only host sync, and the
@@ -740,13 +772,17 @@ class StatsGateway:
                         reqs = [reqs[i] for i in keep]
                 batch = clean
             try:
-                self.session.ingest(ids, batch)
+                placed = self.session.ingest(ids, batch)
             except Exception as e:
+                # a copy from a kept buffer may still be in flight
+                self._kept.pop(key, None)
                 for r in reqs:
                     if not r.future.done():
                         r.future.set_exception(e)
                 self.counters["failed_ingest"] += len(reqs)
                 continue
+            if key in self._kept:
+                self._kept[key].placed = placed
             self.counters["programs_ingest"] += 1
             self._occ_ingest.append(len(reqs))
             self._dirty = True
@@ -756,6 +792,38 @@ class StatsGateway:
             for r in absorbed:
                 self._resolve(r, self._tick, self._lat_ingest)
         return done
+
+    def _stage(self, chunks: list) -> tuple:
+        """Stack equal-shaped ``chunks`` into one batch; returns ``(batch,
+        key, how)``.  Below ``_PIECE_BYTES`` a fresh ``np.stack`` (``how``
+        "heap", ``key`` None): the heap serves it from pages it keeps.  A
+        fresh batch of that size or more would be mapped anew every tick,
+        and the stack would pay a first-touch fault on every page, so it
+        goes into the buffer kept for its ``key`` (rows, length, d, dtype):
+        "new" where this allocates it, "kept" where an earlier tick did.
+        A kept buffer is rewritten only once every device copy placed from
+        it is complete."""
+        if len(chunks) * chunks[0].nbytes < _PIECE_BYTES:
+            return np.stack(chunks), None, "heap"
+        dtype = functools.reduce(np.promote_types, {c.dtype for c in chunks})
+        key = (len(chunks),) + chunks[0].shape + (dtype,)
+        kept = self._kept.get(key)
+        if kept is None:
+            kept = self._kept[key] = _Kept(np.empty(key[:-1], dtype))
+            self.counters["ingest_stage_new"] += 1
+            how = "new"
+        else:
+            jax.block_until_ready(kept.placed)
+            if any(_aliases(p, kept.buf) for p in kept.placed):
+                # the device batch is the buffer: wait for the programs
+                # that read it, which end in the session's state
+                jax.block_until_ready(
+                    jax.tree.leaves(self.session.state_template()))
+            kept.placed = []
+            how = "kept"
+        np.stack(chunks, out=kept.buf)
+        self.counters["ingest_stage_kept"] += 1
+        return kept.buf, key, how
 
     def _apply_sentinel(self, reqs, verdict) -> list:
         """Dispatch each poisoned chunk to its tenant's policy; returns the

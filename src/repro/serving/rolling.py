@@ -542,9 +542,10 @@ class RollingStatsService:
         chunks: jax.Array,
         shard: int = 0,
         t0: Optional[jax.Array] = None,
-    ) -> None:
+    ) -> jax.Array:
         """Absorb one arrival batch: ``chunks[i]`` extends user
-        ``user_ids[i]``'s series on lane ``shard``.
+        ``user_ids[i]``'s series on lane ``shard``.  Returns the batch as
+        placed on the chips; it is ready once they hold their rows.
 
         Args:
           user_ids: (k,) int — distinct users in this batch; ``-1`` marks
@@ -604,7 +605,7 @@ class RollingStatsService:
             # nothing to absorb — and in eviction mode the boundary reset
             # below must not fire for an empty arrival (it would wipe a
             # still-retained bucket without advancing the cursor)
-            return
+            return chunks
         if self.window is not None:
             if t0 is not None:
                 raise ValueError(
@@ -645,6 +646,7 @@ class RollingStatsService:
                     chunks,
                     jax.device_put(t0, self._row_sharding),
                 )
+        return chunks
 
     # -- read path ---------------------------------------------------------
     def partial(self, user_id: int) -> PartialState:

@@ -235,6 +235,40 @@ def gateway_tick(pieces):
         assert gateway._fetch(s.query_batch(s.lay_out(np.arange(n))[0]))[0] == "pieces"
 
 
+def gateway_tick_kept():
+    """Every ingest batch stacked into a kept buffer, in owner-chip order
+    with padding rows, over rounds of uneven blocks: the gateway answers as
+    a one-device session fed the same chunks directly."""
+    n = 10
+    s, whole = session(n), session(n, devices=1)
+    gw = StatsGateway(s)
+    feed = arrivals(n, 11, rounds=5)
+
+    async def round_(ids, chunks):
+        futs = [gw.submit_ingest(int(u), c) for u, c in zip(ids, chunks)]
+        await gw.tick()
+        await asyncio.gather(*futs)
+        futs = [gw.submit_query(u) for u in range(n)]
+        await gw.tick()
+        return await asyncio.gather(*futs)
+
+    piece_bytes, gateway._PIECE_BYTES = gateway._PIECE_BYTES, 1
+    loop = asyncio.new_event_loop()
+    try:
+        for ids, chunks in feed:
+            got = loop.run_until_complete(round_(ids, chunks))
+            whole.ingest(ids, chunks)
+            want = whole.query_batch(np.arange(n))
+            for u, g in enumerate(got):
+                assert_same(g, jax.tree.map(lambda leaf: leaf[u], want))
+    finally:
+        loop.close()
+        gateway._PIECE_BYTES = piece_bytes
+    assert gw.counters["ingest_stage_kept"] == len(feed)
+    assert gw.counters["ingest_pad_rows"] > 0
+    assert len(gw._kept) == 1
+
+
 def window_raises():
     for make in (
         lambda: FrameSession(d=D, num_users=6, window=64, devices=CHIPS),
@@ -261,6 +295,7 @@ CHECKS = {
     "padding_rows": padding_rows,
     "gateway_tick": lambda: gateway_tick(pieces=False),
     "gateway_tick_pieces": lambda: gateway_tick(pieces=True),
+    "gateway_tick_kept": gateway_tick_kept,
     "window_raises": window_raises,
 }
 

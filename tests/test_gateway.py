@@ -494,7 +494,8 @@ def test_each_tick_opens_one_span_per_phase_inside_repro_tick(tmp_path):
     ingest = {e[0]: e[3] for e in inside[0]}
     assert sorted(e[0] for e in inside[0]) == sorted(INGEST_SPANS)
     assert ingest["repro.ingest.stack"] == {"rows": N, "length": c, "bytes": batch,
-                                            "chips": 1, "pad_rows": 0}
+                                            "chips": 1, "pad_rows": 0,
+                                            "staged": "heap"}
     assert ingest["repro.ingest.h2d"] == {"bytes": batch, "chips": 1}
     assert ingest["repro.ingest.dispatch"] == {"rows": N}
     assert ingest["repro.ingest.resolve"] == {"n": N}
@@ -677,3 +678,212 @@ def test_fetch_path_follows_the_device_layout(monkeypatch):
     # nor where the C library cannot keep freed pages
     monkeypatch.setattr(gateway, "_keep_freed_pages", lambda: False)
     assert gateway._fetch(batch)[:2] == ("device_get", 2)
+
+
+# ------------------------------------------ (h) the ingest batch's staging
+
+
+async def _ingest_rounds(gw, feeds, kept=None, ask=()):
+    """Ingest each feed ({tenant: chunk}) in a tick of its own, recording
+    the kept buffers after each when ``kept`` is a list; then answer the
+    tenants in ``ask`` in one more tick."""
+    for feed in feeds:
+        futs = [gw.submit_ingest(u, chunk) for u, chunk in feed.items()]
+        await gw.tick()
+        await asyncio.gather(*futs)
+        if kept is not None:
+            kept.append({key: k.buf for key, k in gw._kept.items()})
+    futs = [gw.submit_query(u) for u in ask]
+    await gw.tick()
+    return await asyncio.gather(*futs)
+
+
+def test_kept_buffer_is_reused_and_answers_match_np_stack(monkeypatch):
+    N, c, rounds = 4, 24, 4
+    feeds = [_chunks(N, c=c, seed=30 + k) for k in range(rounds)]
+    heap = StatsGateway(_session(N))
+    want = run(_ingest_rounds(heap, feeds, ask=range(N)))
+    assert not heap._kept
+
+    monkeypatch.setattr(gateway, "_PIECE_BYTES", 1)
+    gw, kept = StatsGateway(_session(N)), []
+    got = run(_ingest_rounds(gw, feeds, kept, ask=range(N)))
+    key = (N, c, D, np.dtype(np.float32))
+    assert [list(k) for k in kept] == [[key]] * rounds
+    # one buffer, allocated once, written every round
+    assert all(k[key] is kept[0][key] for k in kept)
+    assert gw.counters["ingest_stage_new"] == 1
+    assert gw.counters["ingest_stage_kept"] == rounds
+    np.testing.assert_array_equal(kept[0][key],
+                                  np.stack([feeds[-1][u] for u in range(N)]))
+    # the same bytes reached the device as with a fresh np.stack ...
+    assert [_bits(a) for a in got] == [_bits(a) for a in want]
+    # ... and the answers are the float64 reference's ("paper"
+    # normalization: lag sums over n - h - 1)
+    for u in range(N):
+        x = np.concatenate([feed[u] for feed in feeds]).astype(np.float64)
+        n = len(x)
+        acov = np.stack([x[: n - h].T @ x[h:] / (n - h - 1) for h in range(4)])
+        np.testing.assert_allclose(got[u]["autocovariance"], acov,
+                                   rtol=1e-5, atol=1e-6)
+        assert float(got[u]["moments"]["count"]) == n - 8 + 1
+
+
+@pytest.mark.parametrize("aliased", [False, True], ids=["copied", "aliased"])
+def test_kept_buffer_is_rewritten_only_after_its_device_copy_is_ready(
+        monkeypatch, aliased):
+    monkeypatch.setattr(gateway, "_PIECE_BYTES", 1)
+    # aliased: the device batch is the buffer itself, so the programs that
+    # read it (ending in the session's state) must have run too
+    monkeypatch.setattr(gateway, "_aliases", lambda placed, buf: aliased)
+    N, rounds = 3, 3
+    sess = _session(N)
+    gw = StatsGateway(sess)
+    log, placements = [], []
+    ingest, block = sess.ingest, jax.block_until_ready
+
+    def recording_ingest(ids, batch):
+        (kept,) = gw._kept.values()
+        assert batch is kept.buf
+        placed = ingest(ids, batch)
+        log.append(("placed", len(placements)))
+        placements.append((placed, batch.copy()))
+        return placed
+
+    def recording_block(x):
+        leaves = jax.tree.leaves(x)
+        state = jax.tree.leaves(sess.state_template())
+        for k, (placed, _) in enumerate(placements):
+            if any(leaf is p for leaf in leaves for p in placed):
+                log.append(("ready", k))
+        if any(leaf is s for leaf in leaves for s in state):
+            log.append(("state",))
+        if placements:
+            # not yet rewritten
+            np.testing.assert_array_equal(gw._kept[(N, 16, D, np.dtype(
+                np.float32))].buf, placements[-1][1])
+        return block(x)
+
+    monkeypatch.setattr(sess, "ingest", recording_ingest)
+    monkeypatch.setattr(jax, "block_until_ready", recording_block)
+    run(_ingest_rounds(gw, [_chunks(N, c=16, seed=k) for k in range(rounds)]))
+    waits = [("state",)] if aliased else []
+    assert log == [("placed", 0), ("ready", 0), *waits, ("placed", 1),
+                   ("ready", 1), *waits, ("placed", 2)]
+
+
+@pytest.mark.parametrize("piece_bytes", [None, 1], ids=["heap", "kept"])
+def test_stack_span_says_where_the_batch_was_staged(
+        monkeypatch, tmp_path, piece_bytes):
+    N = 3
+    gw = StatsGateway(_session(N))
+    if piece_bytes is not None:
+        monkeypatch.setattr(gateway, "_PIECE_BYTES", piece_bytes)
+    feeds = [_chunks(N, c=16, seed=k) for k in range(3)]
+    run(_ingest_rounds(gw, feeds[:1]))    # compiles outside the profile
+    _, events = _profiled(tmp_path, _ingest_rounds(gw, feeds[1:]))
+    staged = [e[3]["staged"] for e in sorted(events, key=lambda e: e[1])
+              if e[0] == "repro.ingest.stack"]
+    if piece_bytes is None:
+        # small batches: a fresh np.stack, no buffer kept, nothing counted
+        assert staged == ["heap", "heap"]
+        assert not gw._kept
+        assert "ingest_stage_new" not in gw.counters
+        assert "ingest_stage_kept" not in gw.counters
+    else:
+        assert staged == ["kept", "kept"]
+        assert gw.counters["ingest_stage_new"] == 1
+        assert gw.counters["ingest_stage_kept"] == 3
+
+
+def test_ragged_and_changing_shapes_keep_one_buffer_per_shape_used(
+        monkeypatch):
+    rng = np.random.RandomState(40)
+
+    def feed(lengths):
+        return {u: rng.randn(c, D).astype(np.float32)
+                for u, c in lengths.items()}
+
+    f32 = np.dtype(np.float32)
+    ticks = [
+        # two chunk lengths in one tick: two batches, two buffers
+        (feed({0: 16, 1: 16, 2: 16, 3: 24, 4: 24, 5: 24}),
+         {(3, 16, D, f32), (3, 24, D, f32)}),
+        # another row count: both earlier buffers given up
+        (feed({0: 24, 1: 24, 2: 24, 3: 24}), {(4, 24, D, f32)}),
+        (feed({2: 24, 3: 24, 4: 24, 5: 24}), {(4, 24, D, f32)}),
+        (feed({0: 16, 5: 16}), {(2, 16, D, f32)}),
+    ]
+    feeds = [f for f, _ in ticks]
+    heap = StatsGateway(_session(6))
+    want = run(_ingest_rounds(heap, feeds, ask=range(6)))
+
+    monkeypatch.setattr(gateway, "_PIECE_BYTES", 1)
+    gw, kept = StatsGateway(_session(6)), []
+    got = run(_ingest_rounds(gw, feeds, kept, ask=range(6)))
+    assert [set(k) for k in kept] == [shapes for _, shapes in ticks]
+    assert kept[2][(4, 24, D, f32)] is kept[1][(4, 24, D, f32)]
+    assert gw.counters["ingest_stage_new"] == 4
+    assert gw.counters["ingest_stage_kept"] == 5
+    assert [_bits(a) for a in got] == [_bits(a) for a in want]
+
+
+def test_sentinel_sends_a_poisoned_row_of_a_kept_batch_to_its_policy(
+        monkeypatch):
+    N, c = 4, 16
+
+    def poisoned(seed, bad):
+        chunks = _chunks(N, c=c, seed=seed)
+        for u in bad:
+            chunks[u][3, 1] = np.nan
+        return chunks
+
+    async def serve(gw):
+        gw.set_tenant_policy(1, "sanitize")
+        gw.set_tenant_policy(2, "quarantine")
+        outcomes = []
+        for chunks in (poisoned(50, ()), poisoned(51, (1, 2, 3)),
+                       poisoned(52, ())):
+            futs = {u: gw.submit_ingest(u, chunk) for u, chunk in chunks.items()
+                    if u not in gw.quarantined}
+            await gw.tick()
+            done = await asyncio.gather(*futs.values(), return_exceptions=True)
+            outcomes.append({u: type(r).__name__ if isinstance(r, Exception)
+                             else "ok" for u, r in zip(futs, done)})
+        futs = [gw.submit_query(u) for u in (0, 1, 3)]
+        await gw.tick()
+        return outcomes, await asyncio.gather(*futs)
+
+    cfg = GatewayConfig(sentinel=True, sentinel_policy="reject")
+    heap = StatsGateway(_session(N), cfg)
+    want_outcomes, want = run(serve(heap))
+
+    monkeypatch.setattr(gateway, "_PIECE_BYTES", 1)
+    gw = StatsGateway(_session(N), cfg)
+    outcomes, got = run(serve(gw))
+    assert outcomes == want_outcomes == [
+        {0: "ok", 1: "ok", 2: "ok", 3: "ok"},
+        {0: "ok", 1: "ok", 2: "PoisonedChunk", 3: "PoisonedChunk"},
+        {0: "ok", 1: "ok", 3: "ok"},
+    ]
+    assert gw.quarantined == {2}
+    assert gw.counters["sanitized_chunks"] == 1
+    assert gw.counters["rejected_ingest_poisoned"] == 2
+    # rounds 0 and 1 share a buffer; round 2 has three rows
+    assert gw.counters["ingest_stage_new"] == 2
+    assert gw.counters["ingest_stage_kept"] == 3
+    assert [_bits(a) for a in got] == [_bits(a) for a in want]
+    assert all(np.isfinite(leaf).all()
+               for a in got for leaf in jax.tree.leaves(a))
+
+
+def test_aliases_tells_a_device_view_of_the_buffer_from_a_copy():
+    # a 64-byte aligned host buffer: the CPU device reads it in place
+    raw = np.empty(3 * 16 * D * 4 + 64, np.uint8)
+    off = (-raw.ctypes.data) % 64
+    buf = raw[off:off + 3 * 16 * D * 4].view(np.float32).reshape(3, 16, D)
+    buf[...] = 1.0
+    view = jax.device_put(buf)
+    assert gateway._aliases(view, buf)
+    assert not gateway._aliases(view + 0, buf)
+    assert not gateway._aliases(view, buf[1:].copy())

@@ -30,7 +30,8 @@ def outcomes():
     "matches_one_device_6", "matches_one_device_10",
     "matches_eager_6", "matches_eager_10",
     "lengths", "state_round_trip", "import_tenant", "padding_rows",
-    "gateway_tick", "gateway_tick_pieces", "window_raises",
+    "gateway_tick", "gateway_tick_pieces", "gateway_tick_kept",
+    "window_raises",
 ])
 def test_four_device_session(outcomes, check):
     assert outcomes[check] == "ok", outcomes[check]
